@@ -48,6 +48,21 @@ DEVICE_CLASSES: dict[str, DeviceClass] = {
     d.name: d for d in (H100_NVL, RTX4090_48, RTX4090_24, TPU_V5E, CPU_NODE)
 }
 
+#: JAX ``device_kind`` -> the device class a JAX worker lane advertises
+DEVICE_KINDS: dict[str, str] = {"TPU v5 lite": "tpu-v5e", "cpu": "cpu-node"}
+
+
+def device_class_for_kind(kind: str) -> str:
+    """The device class of a lane on a JAX device of this kind. A kind with
+    no entry is refused: a lane advertising the wrong class would be priced,
+    sized and scheduled as hardware it is not."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(f"no device class for JAX device kind {kind!r} "
+                         f"(known: {sorted(DEVICE_KINDS)})") from None
+
+
 # resource_class -> predicate over device class (hard feasibility, Eq. 1 text)
 RESOURCE_CLASSES: dict[str, float] = {
     # minimum VRAM in GB implied by the class; 0 => CPU ok

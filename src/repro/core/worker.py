@@ -207,6 +207,11 @@ class Executor:
     def execute(self, batch: DispatchBatch, worker: Worker, cas) -> "ExecResult":
         raise NotImplementedError
 
+    def placement(self) -> list[int]:
+        """Ids of the devices holding this executor's state (none for the
+        simulator) — what a lane reports, so a misplaced lane shows."""
+        return []
+
 
 @dataclass
 class ExecResult:

@@ -17,7 +17,7 @@ import threading
 from typing import Any
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 _state = threading.local()
 
@@ -41,7 +41,15 @@ DEFAULT_RULES = {
 }
 
 
+def gspmd(mesh):
+    """``mesh`` with every axis Auto. The models are written for GSPMD
+    propagation steered by ``constrain``; ``jax.make_mesh`` types its axes
+    Explicit, under which every op whose output layout is ambiguous raises."""
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 def install(mesh, rules: dict[str, Any] | None = None) -> None:
+    mesh = gspmd(mesh)
     r = dict(DEFAULT_RULES)
     r["batch"] = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
     if rules:

@@ -18,7 +18,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline(stage_fn: Callable, mesh: Mesh, *, axis: str = "stage",
@@ -72,8 +71,8 @@ def pipeline(stage_fn: Callable, mesh: Mesh, *, axis: str = "stage",
 
     in_specs = (P(axis), P())
     out_specs = P()
-    return shard_map(run, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 def split_stages(layer_params, n_stages: int):

@@ -19,6 +19,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.distributed.logical import gspmd
 from repro.models.attention import AttnParams
 from repro.models.common import ArchConfig
 from repro.models.ffn import MLPParams, MoEParams
@@ -302,5 +303,6 @@ def fit_tree(mesh, spec_tree, shape_tree):
 
 
 def to_named(mesh, spec_tree):
+    mesh = gspmd(mesh)
     return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                         is_leaf=lambda x: isinstance(x, P))

@@ -126,6 +126,24 @@ def embed_init(key, shape, dtype=jnp.float32):
     return (jax.random.normal(key, shape) * 0.02).astype(dtype)
 
 
+def embed_lookup(table: jax.Array, tokens: jax.Array, dtype) -> jax.Array:
+    """Rows ``table[tokens]`` in ``dtype``: (..., T) ids -> (..., T, d).
+
+    Under a mesh with Explicit axes (``jax.make_mesh``'s default) the
+    gather's output sharding cannot be inferred from a vocab-sharded table,
+    so it is given: laid out like the ids, the embedding dim replicated.
+    Callers pin the activation layout afterwards with ``constrain``."""
+    table = table.astype(dtype)
+    sharding = jax.typeof(table).sharding
+    if jax.sharding.AxisType.Explicit not in sharding.mesh.axis_types:
+        return table[tokens]
+    spec = tuple(jax.typeof(tokens).sharding.spec)
+    spec += (None,) * (tokens.ndim + 1 - len(spec))
+    out = jax.sharding.NamedSharding(sharding.mesh,
+                                     jax.sharding.PartitionSpec(*spec))
+    return table.at[tokens].get(out_sharding=out)
+
+
 def split_keys(key, n: int):
     return list(jax.random.split(key, n))
 

@@ -23,7 +23,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .common import ArchConfig
 from .ffn import MoEParams, swiglu
@@ -117,12 +116,12 @@ def moe_block_ep(p: MoEParams, x: jax.Array, cfg: ArchConfig, mesh,
         return out, aux
 
     spec_w = P(axes, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_w, spec_w, spec_w, P(None, None),
                   P(axes, None)),
         out_specs=(P(axes, None), P()),
-        check_rep=False)
+        check_vma=False)
     xt = x.reshape(n, d)
     if n_pad != n:
         xt = jnp.concatenate(

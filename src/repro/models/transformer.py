@@ -23,7 +23,7 @@ from repro.distributed.logical import constrain
 
 from .attention import AttnParams, attention_block, init_attn
 from .common import (ArchConfig, cross_entropy, dense_init, embed_init,
-                     rmsnorm, stacked)
+                     embed_lookup, rmsnorm, stacked)
 from .ffn import MLPParams, MoEParams, init_mlp, init_moe, moe_block, swiglu
 from .mamba2 import (Mamba2Params, MambaState, init_mamba2, init_mamba_state,
                      mamba2_block)
@@ -95,7 +95,8 @@ class DenseLM:
 
     def _embed(self, params, batch):
         cfg = self.cfg
-        h = params["embed"].astype(cfg.compute_dtype)[batch["tokens"]]
+        h = embed_lookup(params["embed"], batch["tokens"],
+                         cfg.compute_dtype)
         if cfg.family == "vlm" and "patch_embeds" in batch:
             pe = batch["patch_embeds"].astype(cfg.compute_dtype) @ \
                 params["patch_proj"].astype(cfg.compute_dtype)
@@ -154,7 +155,7 @@ class DenseLM:
 
     def decode(self, params, tokens, cache):
         cfg = self.cfg
-        h = params["embed"].astype(cfg.compute_dtype)[tokens]   # (B,1,d)
+        h = embed_lookup(params["embed"], tokens, cfg.compute_dtype)   # (B,1,d)
         h, cache = self._cached_trunk(params, h, cache)
         logits = h @ params["lm_head"].astype(cfg.compute_dtype)
         return logits, cache
@@ -269,8 +270,9 @@ class MambaLM:
 
     def loss_fn(self, params, batch):
         cfg = self.cfg
-        h = constrain(params["embed"].astype(cfg.compute_dtype)
-                      [batch["tokens"]], "batch", "seq", "embed")
+        h = constrain(embed_lookup(params["embed"], batch["tokens"],
+                                   cfg.compute_dtype),
+                      "batch", "seq", "embed")
 
         def body(x, lp: SSMLayer):
             m, _ = mamba2_block(lp.mamba, rmsnorm(x, lp.norm, cfg.norm_eps),
@@ -310,14 +312,15 @@ class MambaLM:
 
     def prefill(self, params, batch, cache):
         cfg = self.cfg
-        h = params["embed"].astype(cfg.compute_dtype)[batch["tokens"]]
+        h = embed_lookup(params["embed"], batch["tokens"],
+                         cfg.compute_dtype)
         h, cache = self._run(params, h, cache, step=False)
         logits = h[:, -1:] @ params["lm_head"].astype(cfg.compute_dtype)
         return logits, cache
 
     def decode(self, params, tokens, cache):
         cfg = self.cfg
-        h = params["embed"].astype(cfg.compute_dtype)[tokens]
+        h = embed_lookup(params["embed"], tokens, cfg.compute_dtype)
         h, cache = self._run(params, h, cache, step=True)
         logits = h @ params["lm_head"].astype(cfg.compute_dtype)
         return logits, cache
@@ -368,7 +371,8 @@ class HybridLM:
 
     def loss_fn(self, params, batch):
         cfg = self.cfg
-        h = params["embed"].astype(cfg.compute_dtype)[batch["tokens"]]
+        h = embed_lookup(params["embed"], batch["tokens"],
+                         cfg.compute_dtype)
 
         def inner(x, lp: SSMLayer):
             m, _ = mamba2_block(lp.mamba, rmsnorm(x, lp.norm, cfg.norm_eps),
@@ -427,14 +431,15 @@ class HybridLM:
 
     def prefill(self, params, batch, cache):
         cfg = self.cfg
-        h = params["embed"].astype(cfg.compute_dtype)[batch["tokens"]]
+        h = embed_lookup(params["embed"], batch["tokens"],
+                         cfg.compute_dtype)
         h, cache = self._run(params, h, cache)
         logits = h[:, -1:] @ params["lm_head"].astype(cfg.compute_dtype)
         return logits, cache
 
     def decode(self, params, tokens, cache):
         cfg = self.cfg
-        h = params["embed"].astype(cfg.compute_dtype)[tokens]
+        h = embed_lookup(params["embed"], tokens, cfg.compute_dtype)
         h, cache = self._run(params, h, cache)
         logits = h @ params["lm_head"].astype(cfg.compute_dtype)
         return logits, cache
@@ -573,7 +578,8 @@ class EncDecLM:
         cfg = self.cfg
         enc_out = self.encode(params, batch["frames"])
         cross_kv = self._cross_kv(params, enc_out)
-        h = params["embed"].astype(cfg.compute_dtype)[batch["tokens"]]
+        h = embed_lookup(params["embed"], batch["tokens"],
+                         cfg.compute_dtype)
         h, _ = self._decoder(params, h, cross_kv)
         logits = constrain(
             h @ params["lm_head"].astype(cfg.compute_dtype),
@@ -594,7 +600,8 @@ class EncDecLM:
         cfg = self.cfg
         enc_out = self.encode(params, batch["frames"])
         ck, cv = self._cross_kv(params, enc_out)
-        h = params["embed"].astype(cfg.compute_dtype)[batch["tokens"]]
+        h = embed_lookup(params["embed"], batch["tokens"],
+                         cfg.compute_dtype)
         h, new_kv = self._decoder(params, h, (ck, cv),
                                   kv_cache=(cache["k"], cache["v"]),
                                   index=cache["index"])
@@ -605,7 +612,7 @@ class EncDecLM:
 
     def decode(self, params, tokens, cache):
         cfg = self.cfg
-        h = params["embed"].astype(cfg.compute_dtype)[tokens]
+        h = embed_lookup(params["embed"], tokens, cfg.compute_dtype)
         h, new_kv = self._decoder(
             params, h, (cache["cross_k"], cache["cross_v"]),
             kv_cache=(cache["k"], cache["v"]), index=cache["index"])

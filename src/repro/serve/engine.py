@@ -57,7 +57,12 @@ class ServingEngine:
         self.params = params
         self.n_slots = n_slots
         self.max_len = max_len
-        self.cache = model.init_cache(n_slots, max_len)
+        # the cache is committed to the weights' device: the donated cache
+        # comes back committed, and the first call must see the same
+        # placement as later ones or the prefill compiles twice
+        device = next(iter(jax.tree.leaves(params)[0].devices()))
+        self.cache = jax.device_put(model.init_cache(n_slots, max_len),
+                                    device)
         self.free_slots = list(range(n_slots))
         self.active: dict[int, Request] = {}       # slot -> request
         self.waiting: list[Request] = []
@@ -75,7 +80,8 @@ class ServingEngine:
     def _prefill_fn(self, bucket_len: int) -> Callable:
         """Single-slot prefill, jitted per prompt-length bucket: computes the
         slot's KV/state on a batch-of-1 cache then scatters it into the big
-        cache at the slot index."""
+        cache at the slot index. The slot is a traced argument, so one
+        program serves every slot."""
         if bucket_len in self._prefill_cache:
             return self._prefill_cache[bucket_len]
         model = self.model
@@ -104,7 +110,7 @@ class ServingEngine:
             new_cache["index"] = new_index
             return logits, new_cache
 
-        jitted = jax.jit(fn, donate_argnums=(1,), static_argnums=(4,))
+        jitted = jax.jit(fn, donate_argnums=(1,))
         self._prefill_cache[bucket_len] = jitted
         return jitted
 

@@ -10,7 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import cross_entropy
+from repro.models.common import cross_entropy, embed_lookup
 
 
 def token_logprobs(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -31,7 +31,8 @@ def dpo_loss(model, params, ref_params, batch, *, beta: float = 0.1,
     """Direct Preference Optimization (Rafailov et al. 2023).
     batch: chosen/rejected token+label pairs with response masks."""
     def seq_lp(p, toks, labs, mask):
-        h = model._trunk(p, p["embed"].astype(model.cfg.compute_dtype)[toks])
+        h = model._trunk(p, embed_lookup(p["embed"], toks,
+                                         model.cfg.compute_dtype))
         logits = h @ p["lm_head"].astype(model.cfg.compute_dtype)
         lp = token_logprobs(logits, labs)
         return jnp.sum(lp * mask, axis=-1)
@@ -53,8 +54,8 @@ def ppo_loss(model, params, batch, *, clip: float = 0.2,
     """Clipped-surrogate PPO policy loss over rollout tokens.
     batch: tokens, labels (actions), old_logprobs, advantages, mask."""
     cfg = model.cfg
-    h = model._trunk(params,
-                     params["embed"].astype(cfg.compute_dtype)[batch["tokens"]])
+    h = model._trunk(params, embed_lookup(params["embed"], batch["tokens"],
+                                          cfg.compute_dtype))
     logits = h @ params["lm_head"].astype(cfg.compute_dtype)
     lp = token_logprobs(logits, batch["labels"])
     ratio = jnp.exp(lp - batch["old_logprobs"])
@@ -78,8 +79,8 @@ def reward_model_loss(model, params, batch) -> jax.Array:
     cfg = model.cfg
 
     def score(toks):
-        h = model._trunk(params,
-                         params["embed"].astype(cfg.compute_dtype)[toks])
+        h = model._trunk(params, embed_lookup(params["embed"], toks,
+                                              cfg.compute_dtype))
         return (h[:, -1] @ params["lm_head"].astype(cfg.compute_dtype)
                 )[:, 0].astype(jnp.float32)
 

@@ -18,12 +18,13 @@ import sys
 import threading
 import time
 from pathlib import Path
-from types import SimpleNamespace
 
 import pytest
 
 from repro.core.cas import CAS
+from repro.core.dag import OperatorSpec, OpType
 from repro.core.journal import HEAD_REF, EventJournal
+from repro.core.worker import DispatchBatch, ExecResult, ExecutionGroup
 from repro.fabric import (ClusterAPI, FabricAPI, FabricHTTPServer,
                           FabricService, FollowerAPI, FollowerFabric,
                           RemoteAPI)
@@ -33,6 +34,10 @@ from harness import (DEVICES, QUOTAS, assert_cursor_contract, build_service,
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "scripts"))
 import worker_main as wm                                      # noqa: E402
+
+
+def _done() -> ExecResult:
+    return ExecResult(outputs=[], duration_s=0.0, load_s=0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -333,8 +338,11 @@ class TestWorkerLeaseRetry:
             assert api.calls.count("/worker/heartbeat") == 1
 
     def _stub_batch(self, monkeypatch):
-        spec = SimpleNamespace(model_id=None, h_model=None)
-        batch = SimpleNamespace(groups=[SimpleNamespace(spec=spec)])
+        spec = OperatorSpec(name="op", op_type=OpType.TOOL, inputs=[])
+        batch = DispatchBatch(batch_id=0, h_exec="e", worker_id="w",
+                              admitted_at=0.0, groups=[ExecutionGroup(
+                                  h_task="t", h_exec="e", spec=spec,
+                                  input_hashes=())])
         monkeypatch.setattr(wm, "batch_from_wire", lambda wire: batch)
         monkeypatch.setattr(wm, "result_to_wire", lambda res: {"stub": True})
 
@@ -348,8 +356,7 @@ class TestWorkerLeaseRetry:
                    (503, {"error": "unreachable"}),
                    (409, {"error": "fenced"}))     # then default 200 ok
         wp = _worker(api)
-        wp.executor = SimpleNamespace(
-            execute=lambda batch, shell, cb: SimpleNamespace(failed=False))
+        wp.executor.execute = lambda batch, shell, cb: _done()
         wp.run_one({"lease_id": "L1", "batch": {}})
         assert wp.done == 1
         assert api.calls.count("/worker/complete") == 3
@@ -359,8 +366,7 @@ class TestWorkerLeaseRetry:
         api = RoutedAPI()
         api.defaults["/worker/complete"] = (410, {"error": "fenced_lease"})
         wp = _worker(api)
-        wp.executor = SimpleNamespace(
-            execute=lambda batch, shell, cb: SimpleNamespace(failed=False))
+        wp.executor.execute = lambda batch, shell, cb: _done()
         wp.run_one({"lease_id": "L1", "batch": {}})
         assert wp.done == 0
         assert api.calls.count("/worker/complete") == 1
@@ -373,9 +379,9 @@ class TestWorkerLeaseRetry:
 
         def slow_execute(batch, shell, cb):
             time.sleep(0.05)                 # let the heartbeat fire
-            return SimpleNamespace(failed=False)
+            return _done()
 
-        wp.executor = SimpleNamespace(execute=slow_execute)
+        wp.executor.execute = slow_execute
         wp.run_one({"lease_id": "L1", "batch": {}})
         assert wp.done == 0
         assert "/worker/complete" not in api.calls

@@ -94,6 +94,29 @@ def test_sharded_train_step_runs():
                                rtol=1e-5)
 
 
+def test_embed_lookup_under_explicit_axes():
+    """The one embedding gather of every family, on a vocab-sharded table
+    typed by ``jax.make_mesh``'s Explicit axes, equals the plain gather and
+    is laid out like its ids."""
+    res = run_sub(textwrap.dedent("""
+        import json, jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.models.common import embed_lookup
+
+        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        table = jax.random.normal(jax.random.key(0), (256, 64))
+        ids = jax.random.randint(jax.random.key(1), (8, 32), 0, 256)
+        t = jax.device_put(table, NamedSharding(mesh, P("model", "data")))
+        i = jax.device_put(ids, NamedSharding(mesh, P("data", None)))
+        out = jax.jit(lambda t, i: embed_lookup(t, i, jnp.float32))(t, i)
+        want = np.asarray(table)[np.asarray(ids)]
+        print(json.dumps({"err": float(np.max(np.abs(np.asarray(out) - want))),
+                          "spec": [str(a) for a in out.sharding.spec]}))
+    """))
+    assert res["err"] == 0.0
+    assert res["spec"] == ["data", "None", "None"]
+
+
 def test_moe_ep_matches_gspmd():
     """a2a expert parallelism == grouped GSPMD dispatch, same tokens."""
     res = run_sub(textwrap.dedent("""
