@@ -31,7 +31,7 @@ def main():
     late = Request(rng.integers(0, 128, 5).astype(np.int32),
                    max_new_tokens=6, tenant="C")
     eng.submit(late)
-    print(f"engine occupancy when C arrived: {eng.occupancy:.2f}")
+    print(f"slots busy when C arrived: {len(eng.active)} of {eng.n_slots}")
     while eng.waiting or eng.active:
         done += eng.step()
     print("== continuous batching ==")
@@ -39,7 +39,7 @@ def main():
         print(f"  tenant {r.tenant} req{r.req_id}: {len(r.generated)} tokens "
               f"-> {r.generated[:6]}...")
     assert len(done) == 4 and all(r.done for r in done)
-    print(f"engine steps: {eng.steps}, tokens: {eng.tokens_generated}")
+    print(f"engine: {eng.stats()}")
     print("OK")
 
 

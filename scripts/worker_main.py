@@ -30,7 +30,9 @@ the device class its ``device_kind`` maps to. Weights are random, made from
         --url http://127.0.0.1:8123 --arch llama-3.2-1b
 
 Each lane prints a ``device {...}`` line before it loads weights, a
-``ready {...}`` line after, and one ``batch {...}`` line per batch it served.
+``ready {...}`` line after, and one ``batch {...}`` line per batch it served;
+a JAX lane's ``batch`` line carries, under ``engine``, what its serving
+engine's counters (``ServingEngine.stats()``) added in that batch.
 """
 from __future__ import annotations
 
@@ -160,6 +162,8 @@ class WorkerProcess:
                               args=(lease_id, stop, lost), daemon=True)
         hb.start()
         spec = batch.groups[0].spec
+        engine = getattr(self.executor, "engine", None)
+        before = engine.stats() if engine is not None else None
         try:
             if self.slow_ms > 0:
                 # test/CI hook: hold the batch so a harness can kill -9
@@ -180,7 +184,9 @@ class WorkerProcess:
             "model_id": spec.model_id, "groups": len(batch.groups),
             "speculative": batch.speculative, "wall_s": wall_s,
             "failure": result.failure,
-            "devices": self.executor.placement()}))
+            "devices": self.executor.placement(),
+            "engine": None if engine is None else
+            {k: v - before[k] for k, v in engine.stats().items()}}))
         if lost.is_set():
             say(f"lease {lease_id} revoked/fenced; result dropped")
             return

@@ -103,12 +103,11 @@ class JaxExecutor(Executor):
         if failure is not None:
             return ExecResult(outputs=[], duration_s=0.0, load_s=0.0,
                               failed=True, failure=failure)
-        cold = bool(spec.model_id) and not worker.is_hot_for(spec.h_model)
         with jax.default_device(self.device):
             outputs = self._run(batch, spec)
         dur = time.perf_counter() - t0
-        load_s = 0.15 if cold else 0.0     # assumed weight upload
-        return ExecResult(outputs=outputs, duration_s=dur, load_s=load_s,
+        # the lane's weights are resident from construction: nothing loads
+        return ExecResult(outputs=outputs, duration_s=dur, load_s=0.0,
                           flops=0.0)
 
     def _run(self, batch: DispatchBatch, spec) -> list[bytes]:

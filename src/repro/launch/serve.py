@@ -4,8 +4,9 @@
         --requests 16 --max-new 12
 
 Boots the continuous-batching engine for one H_exec (arch + params), streams
-a batch of multi-tenant requests through it, and reports throughput +
-occupancy — the same code path the fabric's JaxExecutor drives.
+a batch of multi-tenant requests through it, and reports throughput and
+the engine's counters (``ServingEngine.stats()``) — the same code path the
+fabric's JaxExecutor drives.
 """
 from __future__ import annotations
 
@@ -53,8 +54,7 @@ def main(argv=None) -> dict:
     dt = time.time() - t0
     result = {
         "requests": len(done),
-        "tokens_generated": eng.tokens_generated,
-        "engine_steps": eng.steps,
+        **eng.stats(),
         "wall_s": round(dt, 2),
         "tok_per_s": round(eng.tokens_generated / dt, 1),
         "tenants": sorted({r.tenant for r in done}),

@@ -110,6 +110,28 @@ def test_lane_serves_batch_eval_including_its_aggregate(executor, capsys):
                if ln.startswith("batch {")]
     assert batches and all(b["failure"] is None for b in batches)
     assert all(b["devices"] == [executor.device.id] for b in batches)
+    # each line carries what the batch added to the engine's counters
+    for b in batches:
+        eng = b["engine"]
+        assert set(eng) == set(executor.engine.stats())
+        if b["op_type"] == "aggregate":
+            assert eng["prefills"] == eng["host_reads"] == 0
+        else:
+            assert eng["prefills"] == b["groups"]
+            assert eng["tokens_generated"] + eng["prefills"] \
+                == b["groups"] * executor.gen_tokens
+            assert eng["host_reads"] >= b["groups"] * executor.gen_tokens
+
+
+def test_served_batch_reports_no_load_time(executor):
+    """The lane's weights are resident from construction, so even a shell
+    that is cold for the model loads nothing."""
+    shell = _shell()
+    spec = _batch(OpType.GENERATE, ARCH).groups[0].spec
+    assert not shell.is_hot_for(spec.h_model)
+    result = executor.execute(_batch(OpType.GENERATE, ARCH), shell, None)
+    assert not result.failed and len(result.outputs) == 1
+    assert result.load_s == 0.0
 
 
 def test_op_for_another_model_fails_visibly(executor):

@@ -94,3 +94,107 @@ def test_greedy_is_deterministic(served):
         return eng.run([Request(p.copy(), max_new_tokens=5)])[0].generated
 
     assert once() == once()      # deterministic -> publishable by content hash
+
+
+# ----------------------------------------------------- counters and spans --
+def test_counters_after_a_closed_batch(served):
+    """``stats()`` after a closed batch: one prefill per request, their
+    prompt tokens, and a host read per sampled token plus one per length
+    check; the tokens still equal the sequential oracle's."""
+    cfg, model, params = served
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (5, 9, 13)]
+    n_new = (6, 3, 5)
+    eng = ServingEngine(model, params, n_slots=2, max_len=256)
+    done = eng.run([Request(p, max_new_tokens=k)
+                    for p, k in zip(prompts, n_new)])
+    done.sort(key=lambda r: r.req_id)
+    for req, p, k in zip(done, prompts, n_new):
+        assert req.generated == greedy_reference(model, params, p, k)
+    s = eng.stats()
+    assert s["prefills"] == len(prompts)
+    assert s["prompt_tokens"] == sum(len(p) for p in prompts)
+    tokens = sum(len(r.generated) for r in done)
+    assert s["tokens_generated"] + s["prefills"] == tokens
+    # the first token comes from prefill; a decode token is length-checked
+    # unless it is the request's last
+    length_reads = sum(len(r.generated) - 2 for r in done)
+    assert s["host_reads"] == tokens + length_reads
+    assert s["steps"] == eng.steps > 0
+    assert s["prefill_s"] > 0 and s["decode_s"] > 0
+
+
+def test_compiles_count_a_new_prompt_length_once(served):
+    cfg, model, params = served
+    rng = np.random.default_rng(5)
+    eng = ServingEngine(model, params, n_slots=2, max_len=128)
+
+    def serve(n):
+        before = eng.compiles
+        eng.run([Request(rng.integers(0, 128, n).astype(np.int32),
+                         max_new_tokens=3)])
+        return eng.compiles - before
+
+    assert serve(5) > 0              # the prefill, decode and their reads
+    assert serve(11) == 1            # only the new length's prefill
+    assert serve(11) == 0
+    assert serve(5) == 0
+    assert eng.stats()["compiles"] == eng.compiles
+
+
+def _host_spans(log_dir) -> list[tuple[int, int, str, dict]]:
+    from jax.profiler import ProfileData
+    paths = list(log_dir.rglob("*.xplane.pb"))
+    assert len(paths) == 1
+    out = []
+    for plane in ProfileData.from_file(str(paths[0])).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("engine."):
+                    out.append((e.start_ns, e.start_ns + e.duration_ns,
+                                e.name, dict(e.stats)))
+    return out
+
+
+def test_profiler_trace_nests_the_engine_spans(served, tmp_path):
+    """With a trace on, each step's decode, sampling and retirement spans
+    lie inside its ``engine.step``, each prefill inside an
+    ``engine.admit``, with the request's id, length and slot."""
+    cfg, model, params = served
+    rng = np.random.default_rng(6)
+    eng = ServingEngine(model, params, n_slots=2, max_len=128)
+    eng.run([Request(rng.integers(0, 128, 4).astype(np.int32),
+                     max_new_tokens=2)])                 # warm-up
+    reqs = [Request(rng.integers(0, 128, 4).astype(np.int32),
+                    max_new_tokens=3) for _ in range(2)]
+    steps = eng.steps
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(tmp_path)
+    by = {}
+    for s in spans:
+        by.setdefault(s[2], []).append(s)
+
+    def inside(span, parent):
+        return any(p[0] <= span[0] and span[1] <= p[1] for p in by[parent])
+
+    assert len(by["engine.step"]) == eng.steps - steps
+    for name in ("engine.decode", "engine.sample", "engine.retire",
+                 "engine.admit"):
+        assert by[name] and all(inside(s, "engine.step") for s in by[name])
+    assert all(inside(s, "engine.admit") for s in by["engine.prefill"])
+    ids = {r.req_id for r in reqs}
+    assert {s[3]["req_id"] for s in by["engine.prefill"]} == ids
+    assert {(s[3]["tokens"], s[3]["slot"]) for s in by["engine.prefill"]} \
+        == {(4, 0), (4, 1)}
+    assert {s[3]["req_id"] for s in by["engine.retire"]} == ids
+    # one sample span per host token read, one retire span per decode token
+    assert len(by["engine.sample"]) == sum(len(r.generated) for r in reqs)
+    assert len(by["engine.retire"]) == sum(len(r.generated) - 1
+                                           for r in reqs)
