@@ -41,11 +41,11 @@ def main(fast: bool = False) -> list[str]:
     # decode attention at 8k context
     S = 2048 if fast else 8192
     q1 = jax.random.normal(key, (4, Hq, hd), jnp.float32)
-    kc = jax.random.normal(jax.random.fold_in(key, 3), (4, S, Hkv, hd))
-    vc = jax.random.normal(jax.random.fold_in(key, 4), (4, S, Hkv, hd))
+    kc = jax.random.normal(jax.random.fold_in(key, 3), (1, 4, Hkv, hd, S))
+    vc = jax.random.normal(jax.random.fold_in(key, 4), (1, 4, Hkv, hd, S))
     lens = jnp.array([S, S // 2, S // 4, 100], jnp.int32)
     us = _time(lambda *a: decode_attention(*a, backend="xla"),
-               q1, kc, vc, lens)
+               q1, kc, vc, jnp.int32(0), lens)
     lines.append(csv_line("kernel.decode_attention_xla", us,
                           f"B4xS{S};ragged-lengths;cpu-fallback"))
 

@@ -184,15 +184,22 @@ def param_specs(cfg: ArchConfig, mesh):
 
 
 # ---------------------------------------------------------------------------
-def _kv_spec(cfg: ArchConfig, mesh, *, lead: int) -> P:
-    """(lead..., B, S, H_kv, hd): shard heads over model if divisible-ish,
-    else shard head_dim."""
-    m = _axis(mesh, "model")
-    pre = (None,) * lead
-    b = batch_axes(mesh)
-    if cfg.n_kv_heads >= m:
-        return P(*pre, b, None, "model", None)
-    return P(*pre, b, None, None, "model")
+def _kv_heads_hd(cfg: ArchConfig, mesh) -> tuple:
+    """Mesh axes of (H_kv, hd): heads over model if divisible-ish, else
+    head_dim."""
+    if cfg.n_kv_heads >= _axis(mesh, "model"):
+        return "model", None
+    return None, "model"
+
+
+def _kv_spec(cfg: ArchConfig, mesh) -> P:
+    """Stacked self-attention cache (L, B, H_kv, hd, S), positions minor."""
+    return P(None, batch_axes(mesh), *_kv_heads_hd(cfg, mesh), None)
+
+
+def _cross_kv_spec(cfg: ArchConfig, mesh) -> P:
+    """Stacked cross-attention K/V (L, B, T_enc, H_kv, hd)."""
+    return P(None, batch_axes(mesh), None, *_kv_heads_hd(cfg, mesh))
 
 
 def cache_specs(cfg: ArchConfig, mesh):
@@ -200,22 +207,22 @@ def cache_specs(cfg: ArchConfig, mesh):
     fam = cfg.family
     b = batch_axes(mesh)
     if fam in ("dense", "vlm", "moe"):
-        return {"k": _kv_spec(cfg, mesh, lead=1),
-                "v": _kv_spec(cfg, mesh, lead=1),
+        return {"k": _kv_spec(cfg, mesh),
+                "v": _kv_spec(cfg, mesh),
                 "index": P(b)}
     if fam == "ssm":
         return {"state": _mamba_state_spec(cfg, mesh, lead=1),
                 "index": P(b)}
     if fam == "hybrid":
         return {"state": _mamba_state_spec(cfg, mesh, lead=2),
-                "k": _kv_spec(cfg, mesh, lead=1),
-                "v": _kv_spec(cfg, mesh, lead=1),
+                "k": _kv_spec(cfg, mesh),
+                "v": _kv_spec(cfg, mesh),
                 "index": P(b)}
     if fam == "encdec":
-        return {"k": _kv_spec(cfg, mesh, lead=1),
-                "v": _kv_spec(cfg, mesh, lead=1),
-                "cross_k": _kv_spec(cfg, mesh, lead=1),
-                "cross_v": _kv_spec(cfg, mesh, lead=1),
+        return {"k": _kv_spec(cfg, mesh),
+                "v": _kv_spec(cfg, mesh),
+                "cross_k": _cross_kv_spec(cfg, mesh),
+                "cross_v": _cross_kv_spec(cfg, mesh),
                 "index": P(b)}
     raise ValueError(fam)
 

@@ -3,10 +3,17 @@ cache, GQA, per-sequence valid lengths.
 
 This is the steady-state op of the fabric's continuous-batching workers —
 purely memory-bound (arithmetic intensity ~ 2 FLOPs/byte), so the tiling goal
-is streaming the KV cache HBM->VMEM in (blk_k, hd) tiles exactly once while
+is streaming the KV cache HBM->VMEM in (hd, blk_k) tiles exactly once while
 the (g, hd) query tile for the kv-head group stays resident. Grid
 (B, Hkv, S/blk_k); the kv dimension is sequential and carries the online-
 softmax state (m, l, acc) for the whole head-group tile in VMEM.
+
+The kernel reads the models' stacked cache as it is stored,
+(L, B, Hkv, hd, S) with positions minor: the layer index and the lengths
+are scalar-prefetched and pick each tile in the BlockSpec index map, so a
+decode step's layer scan hands the kernel the whole cache and nothing is
+sliced or transposed per layer. Positions minor also keeps the tiles
+unpadded: hd 64 or 96 as the lane axis would be padded to 128.
 
 Invalid cache positions (>= length[b]) are masked, so one compiled kernel
 serves every request mix in the engine's slots.
@@ -23,7 +30,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
+def _decode_kernel(layer_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                    acc_ref, m_ref, l_ref, *, blk_k: int, n_k: int,
                    scale: float):
     b = pl.program_id(0)
@@ -42,8 +49,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ki * blk_k < length)
     def _compute():
         q = q_ref[...].astype(jnp.float32) * scale       # (g, hd)
-        k = k_ref[...].astype(jnp.float32)               # (blk_k, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        k = k_ref[...].astype(jnp.float32)               # (hd, blk_k)
+        s = jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (g, blk_k)
         kpos = ki * blk_k + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
@@ -53,9 +60,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         corr = jnp.exp(m_prev - m_cur)
         p = jnp.exp(s - m_cur[:, None])
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        v = v_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)               # (hd, blk_k)
         acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_cur
 
@@ -66,13 +73,14 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("blk_k", "interpret"))
-def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     lengths: jax.Array, *, blk_k: int = 256,
-                     interpret: bool = False) -> jax.Array:
-    """q: (B, Hq, hd); k/v: (B, S, Hkv, hd); lengths: (B,) int32.
+def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                     layer: jax.Array, lengths: jax.Array, *,
+                     blk_k: int = 256, interpret: bool = False) -> jax.Array:
+    """q: (B, Hq, hd); k_cache/v_cache: (L, B, Hkv, hd, S); layer: ()
+    int32, the layer whose K/V are read; lengths: (B,) int32.
     Returns (B, Hq, hd)."""
     B, Hq, hd = q.shape
-    _, S, Hkv, _ = k.shape
+    _, _, Hkv, _, S = k_cache.shape
     g = Hq // Hkv
     blk_k = min(blk_k, S)
     assert S % blk_k == 0
@@ -80,21 +88,19 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = 1.0 / (hd ** 0.5)
 
     qt = q.reshape(B, Hkv, g, hd)
-    kt = k.transpose(0, 2, 1, 3)          # (B, Hkv, S, hd)
-    vt = v.transpose(0, 2, 1, 3)
+    kv_spec = pl.BlockSpec((None, None, None, hd, blk_k),
+                           lambda b, h, ki, layer, _: (layer[0], b, h, 0, ki))
 
     kernel = functools.partial(_decode_kernel, blk_k=blk_k, n_k=n_k,
                                scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,            # lengths land in SMEM
+        num_scalar_prefetch=2,            # layer and lengths land in SMEM
         grid=(B, Hkv, n_k),
         in_specs=[
             pl.BlockSpec((None, None, g, hd),
                          lambda b, h, ki, *_: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, blk_k, hd),
-                         lambda b, h, ki, *_: (b, h, ki, 0)),
-            pl.BlockSpec((None, None, blk_k, hd),
-                         lambda b, h, ki, *_: (b, h, ki, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((None, None, g, hd),
                                lambda b, h, ki, *_: (b, h, 0, 0)),
@@ -111,5 +117,6 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), qt, kt, vt)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      lengths.astype(jnp.int32), qt, k_cache, v_cache)
     return out.reshape(B, Hq, hd)

@@ -47,11 +47,14 @@ def flash_attention(q, k, v, *, causal: bool = True, backend: str | None = None,
                          interpret=(be == "interpret"), **kw)
 
 
-def decode_attention(q, k, v, lengths, *, backend: str | None = None, **kw):
+def decode_attention(q, k_cache, v_cache, layer, lengths, *,
+                     backend: str | None = None, **kw):
+    """k_cache/v_cache: the stacked (L, B, Hkv, hd, S) cache; ``layer``
+    picks the layer the query attends."""
     be = _resolve(backend)
     if be == "xla":
-        return ref.decode_attention_ref(q, k, v, lengths)
-    return _decode_pallas(q, k, v, lengths,
+        return ref.decode_attention_ref(q, k_cache, v_cache, layer, lengths)
+    return _decode_pallas(q, k_cache, v_cache, layer, lengths,
                           interpret=(be == "interpret"), **kw)
 
 

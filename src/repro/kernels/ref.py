@@ -31,20 +31,23 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.reshape(B, T, Hq, hd).astype(q.dtype)
 
 
-def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
+def decode_attention_ref(q: jax.Array, k_cache: jax.Array,
+                         v_cache: jax.Array, layer: jax.Array,
                          lengths: jax.Array) -> jax.Array:
-    """Single-token GQA decode. q: (B, Hq, hd); k/v: (B, S, Hkv, hd);
-    lengths: (B,) valid KV prefix. Returns (B, Hq, hd)."""
+    """Single-token GQA decode. q: (B, Hq, hd); k_cache/v_cache: the
+    stacked (L, B, Hkv, hd, S) cache, read at ``layer``; lengths: (B,)
+    valid KV prefix. Returns (B, Hq, hd)."""
     B, Hq, hd = q.shape
-    _, S, Hkv, _ = k.shape
+    _, _, Hkv, _, S = k_cache.shape
     g = Hq // Hkv
+    k, v = k_cache[layer], v_cache[layer]                    # (B,Hkv,hd,S)
     qg = q.reshape(B, Hkv, g, hd).astype(jnp.float32)
-    scores = jnp.einsum("bhgd,bshd->bhgs", qg,
+    scores = jnp.einsum("bhgd,bhds->bhgs", qg,
                         k.astype(jnp.float32)) / jnp.sqrt(float(hd))
     valid = jnp.arange(S)[None, :] < lengths[:, None]        # (B, S)
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhgs,bshd->bhgd", p, v.astype(jnp.float32))
+    out = jnp.einsum("bhgs,bhds->bhgd", p, v.astype(jnp.float32))
     return out.reshape(B, Hq, hd).astype(q.dtype)
 
 

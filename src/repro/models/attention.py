@@ -1,9 +1,12 @@
 """GQA attention (train/prefill/decode) with optional Pallas kernel dispatch.
 
 Shapes follow the (B, T, H, hd) convention. KV caches are slot-contiguous
-(B, L_max, H_kv, hd) — the TPU-native adaptation of paged attention (see
-DESIGN.md §3): contiguous blocks DMA cleanly into VMEM; per-sequence lengths
-mask validity instead of page tables.
+and stacked over layers, (L, B, H_kv, hd, L_max) with positions minor — the
+TPU-native adaptation of paged attention (see DESIGN.md §3): contiguous
+blocks DMA cleanly into VMEM; per-sequence lengths mask validity instead of
+page tables. Positions minor keeps hd (64, 96) off the 128-wide lane axis,
+so the stored cache is unpadded, and it is the layout the decode kernel
+tiles, so the layer scan carries the cache and writes rows in place.
 """
 from __future__ import annotations
 
@@ -38,8 +41,7 @@ def init_attn(key, cfg: ArchConfig) -> AttnParams:
 
 
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                      causal: bool, kv_len: jax.Array | None = None,
-                      blk: int = 512) -> jax.Array:
+                      causal: bool, blk: int = 512) -> jax.Array:
     """Flash-style attention in PURE XLA: lax.scan over KV blocks with
     online softmax, rematerialized — the S^2 score tensor never exists.
     This is the lowering the dry-run compiles (the Pallas kernel plays this
@@ -62,18 +64,10 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         m, l, acc = carry
         k_b, v_b, b_idx = inp
         s = jnp.einsum("bthgd,bkhd->bhgtk", qg, k_b.astype(jnp.float32))
-        kpos = b_idx * blk + jnp.arange(blk)
-        mask = None
         if causal:
+            kpos = b_idx * blk + jnp.arange(blk)
             mask = qpos[:, None] >= kpos[None, :]
-        if kv_len is not None:
-            valid = kpos[None, :] < kv_len[:, None]        # (B, blk)
-            vm = valid[:, None, None, None, :]
-            mask = vm if mask is None else (mask[None, None, None] & vm)
-        if mask is not None:
-            if mask.ndim == 2:
-                mask = mask[None, None, None]
-            s = jnp.where(mask, s, NEG_INF)
+            s = jnp.where(mask[None, None, None], s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         corr = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new[..., None])
@@ -92,11 +86,9 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 def gqa_scores_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                         causal: bool, q_offset: jax.Array | int = 0,
-                         kv_len: jax.Array | None = None) -> jax.Array:
-    """Reference XLA attention. q: (B, Tq, Hq, hd), k/v: (B, Tk, Hkv, hd).
-    ``q_offset``: absolute position of q[0] (decode); ``kv_len``: per-batch
-    valid KV prefix length (B,) for slot caches."""
+                         causal: bool) -> jax.Array:
+    """Reference XLA attention. q: (B, Tq, Hq, hd), k/v: (B, Tk, Hkv, hd);
+    causal aligns q[0] with k[0]."""
     B, Tq, Hq, hd = q.shape
     _, Tk, Hkv, _ = k.shape
     g = Hq // Hkv
@@ -109,35 +101,51 @@ def gqa_scores_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         # on head sharding). The TPU serving path never materializes this —
         # the Pallas flash kernel streams KV blocks instead.
         scores = constrain(scores, "batch", None, None, "q_seq", None)
-    mask = None
     if causal:
-        qpos = jnp.arange(Tq) + q_offset
-        kpos = jnp.arange(Tk)
-        mask = qpos[:, None] >= kpos[None, :]
-    if kv_len is not None:
-        valid = jnp.arange(Tk)[None, :] < kv_len[:, None]     # (B, Tk)
-        vmask = valid[:, None, None, None, :]
-        mask = vmask if mask is None else (mask[None, None, None] & vmask)
-    if mask is not None:
-        if mask.ndim == 2:
-            mask = mask[None, None, None]
-        scores = jnp.where(mask, scores, NEG_INF)
+        mask = jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :]
+        scores = jnp.where(mask[None, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
     return out.reshape(B, Tq, Hq, hd).astype(q.dtype)
+
+
+def kv_zeros(cfg: ArchConfig, n_layers: int, batch: int,
+             max_len: int) -> jax.Array:
+    """An empty stacked K or V cache, (n_layers, batch, Hkv, hd, max_len)."""
+    return jnp.zeros((n_layers, batch, cfg.n_kv_heads, cfg.hd, max_len),
+                     cfg.compute_dtype)
+
+
+def write_rows(cache: jax.Array, rows: jax.Array, layer: jax.Array,
+               index: jax.Array) -> jax.Array:
+    """Write ``rows`` (B, T, Hkv, hd) into the stacked cache
+    (L, B, Hkv, hd, S) at ``layer``, slot b's rows from position
+    ``index[b]``. One dynamic_update_slice per slot keeps the write in
+    place in the layer scan's carried cache (a single scatter over the
+    slots made XLA pick another layout and copy the whole cache)."""
+    rows = jnp.moveaxis(rows, 1, -1).astype(cache.dtype)   # (B,Hkv,hd,T)
+    for b in range(rows.shape[0]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[b][None, None], (layer, b, 0, 0, index[b]))
+    return cache
 
 
 def attention_block(p: AttnParams, x: jax.Array, cfg: ArchConfig, *,
                     causal: bool = True,
                     positions: jax.Array | None = None,
                     kv_cache: tuple[jax.Array, jax.Array] | None = None,
+                    layer: jax.Array | None = None,
                     cache_index: jax.Array | None = None,
                     cross_kv: tuple[jax.Array, jax.Array] | None = None,
                     use_rope: bool = True,
                     ) -> tuple[jax.Array, tuple[jax.Array, jax.Array] | None]:
     """One attention sublayer (no residual/norm). Modes:
       * train/prefill: kv_cache None -> self-attention over x;
-      * decode: kv_cache (K, V) slot caches + cache_index -> append then attend;
+      * cached: kv_cache (K, V), the stacked (L, B, Hkv, hd, S) caches,
+        with ``layer`` and cache_index -> write this call's rows at
+        ``layer``, then attend. T == 1 (decode) attends the cache; T > 1
+        (prefill) attends its own K/V, the slot's whole prefix under the
+        contract that a prefill starts at index 0 on a fresh slot;
       * cross: cross_kv given -> encoder-decoder attention (ignores cache).
     Returns (out, updated_cache).
     """
@@ -163,29 +171,21 @@ def attention_block(p: AttnParams, x: jax.Array, cfg: ArchConfig, *,
 
     new_cache = None
     if kv_cache is not None:
-        ck, cv = kv_cache                     # (B, L_max, Hkv, hd)
-        idx = cache_index if cache_index is not None else jnp.zeros(
-            (B,), jnp.int32)
-        ck = jax.vmap(
-            lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
-        )(ck, k, idx)
-        cv = jax.vmap(
-            lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (i, 0, 0))
-        )(cv, v, idx)
+        ck = write_rows(kv_cache[0], k, layer, cache_index)
+        cv = write_rows(kv_cache[1], v, layer, cache_index)
         new_cache = (ck, cv)
         if T == 1:
             # decode: every valid cached position is <= the current one,
-            # so kv_len masking alone is exact (no causal matrix needed).
+            # so length masking alone is exact (no causal matrix needed).
             # Hot path -> Pallas decode-attention kernel on TPU.
             from repro.kernels import ops as kops
-            out = kops.decode_attention(q[:, 0], ck, cv, idx + 1)[:, None]
+            out = kops.decode_attention(q[:, 0], ck, cv, layer,
+                                        cache_index + 1)[:, None]
         elif T >= 1024:
-            # long prefill-into-cache: flash-style chunked lowering
-            out = chunked_attention(q, ck, cv, causal=True, kv_len=idx + T)
+            # long prefill: flash-style chunked lowering
+            out = chunked_attention(q, k, v, causal=True)
         else:
-            # prefill-into-cache (idx == 0 per slot-allocation contract)
-            out = gqa_scores_attention(q, ck, cv, causal=True,
-                                       q_offset=0, kv_len=idx + T)
+            out = gqa_scores_attention(q, k, v, causal=True)
     else:
         if causal and q.shape[1] == k.shape[1]:
             # train/prefill hot path -> Pallas flash attention on TPU

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from repro.distributed.logical import constrain
 
-from .attention import AttnParams, attention_block, init_attn
+from .attention import AttnParams, attention_block, init_attn, kv_zeros
 from .common import (ArchConfig, cross_entropy, dense_init, embed_init,
                      embed_lookup, rmsnorm, stacked)
 from .ffn import MLPParams, MoEParams, init_mlp, init_moe, moe_block, swiglu
@@ -116,10 +116,12 @@ class DenseLM:
     # -- serving ------------------------------------------------------------
     def init_cache(self, batch: int, max_len: int):
         cfg = self.cfg
-        shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.hd)
-        return {"k": jnp.zeros(shape, cfg.compute_dtype),
-                "v": jnp.zeros(shape, cfg.compute_dtype),
+        return {"k": kv_zeros(cfg, cfg.n_layers, batch, max_len),
+                "v": kv_zeros(cfg, cfg.n_layers, batch, max_len),
                 "index": jnp.zeros((batch,), jnp.int32)}
+
+    def _ffn(self, lp: DenseLayer, x):
+        return swiglu(lp.mlp, x, self.cfg.compute_dtype)
 
     def _cached_trunk(self, params, h, cache):
         cfg = self.cfg
@@ -130,18 +132,19 @@ class DenseLM:
         # the T==1 residual replicated.
         res_axis = "seq_res" if h.shape[1] > 1 else "seq"
 
-        def body(x, inp):
-            lp, ck, cv = inp
-            a, new = attention_block(
+        def body(carry, inp):
+            x, ck, cv = carry
+            lp, layer = inp
+            a, (ck, cv) = attention_block(
                 lp.attn, rmsnorm(x, lp.norm1, cfg.norm_eps), cfg,
-                kv_cache=(ck, cv), cache_index=idx)
+                kv_cache=(ck, cv), layer=layer, cache_index=idx)
             x = constrain(x + a, "batch", "seq", "embed")
-            x = x + swiglu(lp.mlp, rmsnorm(x, lp.norm2, cfg.norm_eps),
-                           cfg.compute_dtype)
-            return constrain(x, "batch", res_axis, "embed"), new
+            x = x + self._ffn(lp, rmsnorm(x, lp.norm2, cfg.norm_eps))
+            return (constrain(x, "batch", res_axis, "embed"), ck, cv), None
 
-        h, (nk, nv) = jax.lax.scan(_maybe_remat(body, cfg), h,
-                                   (params["layers"], cache["k"], cache["v"]))
+        (h, nk, nv), _ = jax.lax.scan(
+            body, (h, cache["k"], cache["v"]),
+            (params["layers"], jnp.arange(cfg.n_layers)))
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         new_cache = {"k": nk, "v": nv, "index": idx + h.shape[1]}
         return h, new_cache
@@ -217,24 +220,8 @@ class MoELM(DenseLM):
         return cross_entropy(logits, batch["labels"],
                              batch.get("loss_mask")) + self.AUX_WEIGHT * aux
 
-    def _cached_trunk(self, params, h, cache):
-        cfg = self.cfg
-        idx = cache["index"]
-        res_axis = "seq_res" if h.shape[1] > 1 else "seq"
-
-        def body(x, inp):
-            lp, ck, cv = inp
-            a, new = attention_block(
-                lp.attn, rmsnorm(x, lp.norm1, cfg.norm_eps), cfg,
-                kv_cache=(ck, cv), cache_index=idx)
-            x = constrain(x + a, "batch", "seq", "embed")
-            m, _ = moe_block(lp.moe, rmsnorm(x, lp.norm2, cfg.norm_eps), cfg)
-            return constrain(x + m, "batch", res_axis, "embed"), new
-
-        h, (nk, nv) = jax.lax.scan(_maybe_remat(body, cfg), h,
-                                   (params["layers"], cache["k"], cache["v"]))
-        h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
-        return h, {"k": nk, "v": nv, "index": idx + h.shape[1]}
+    def _ffn(self, lp: MoELayer, x):
+        return moe_block(lp.moe, x, self.cfg)[0]
 
 
 # ===========================================================================
@@ -357,12 +344,13 @@ class HybridLM:
                                   dtype=cfg.param_dtype),
         }
 
-    def _shared_block(self, params, x, *, kv_cache=None, cache_index=None):
+    def _shared_block(self, params, x, *, kv_cache=None, layer=None,
+                      cache_index=None):
         cfg = self.cfg
         a, new = attention_block(
             params["shared_attn"],
             rmsnorm(x, params["shared_norm1"], cfg.norm_eps), cfg,
-            kv_cache=kv_cache, cache_index=cache_index)
+            kv_cache=kv_cache, layer=layer, cache_index=cache_index)
         x = x + a
         x = x + swiglu(params["shared_mlp"],
                        rmsnorm(x, params["shared_norm2"], cfg.norm_eps),
@@ -398,10 +386,9 @@ class HybridLM:
         states = jax.tree.map(
             lambda x: jnp.broadcast_to(
                 x, (self.n_groups, cfg.attn_every) + x.shape), one)
-        kshape = (self.n_groups, batch, max_len, cfg.n_kv_heads, cfg.hd)
         return {"state": states,
-                "k": jnp.zeros(kshape, cfg.compute_dtype),
-                "v": jnp.zeros(kshape, cfg.compute_dtype),
+                "k": kv_zeros(cfg, self.n_groups, batch, max_len),
+                "v": kv_zeros(cfg, self.n_groups, batch, max_len),
                 "index": jnp.zeros((batch,), jnp.int32)}
 
     def _run(self, params, h, cache):
@@ -415,16 +402,18 @@ class HybridLM:
                 state=MambaState(*st), return_state=True)
             return x + m, tuple(new_st)
 
-        def group(x, inp):
-            glp, gst, ck, cv = inp
-            x, new_kv = self._shared_block(params, x, kv_cache=(ck, cv),
-                                           cache_index=idx)
+        def group(carry, inp):
+            x, ck, cv = carry
+            glp, gst, g = inp
+            x, (ck, cv) = self._shared_block(params, x, kv_cache=(ck, cv),
+                                             layer=g, cache_index=idx)
             x, new_states = jax.lax.scan(inner, x, (glp, gst))
-            return x, (new_states, new_kv[0], new_kv[1])
+            return (x, ck, cv), new_states
 
-        h, (new_states, nk, nv) = jax.lax.scan(
-            group, h, (params["layers"], tuple(cache["state"]),
-                       cache["k"], cache["v"]))
+        (h, nk, nv), new_states = jax.lax.scan(
+            group, (h, cache["k"], cache["v"]),
+            (params["layers"], tuple(cache["state"]),
+             jnp.arange(self.n_groups)))
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         return h, {"state": MambaState(*new_states), "k": nk, "v": nv,
                    "index": idx + h.shape[1]}
@@ -545,12 +534,10 @@ class EncDecLM:
         cfg = self.cfg
         ck, cv = cross_kv
 
-        def body(x, inp):
-            lp, cross_k_l, cross_v_l, self_k_l, self_v_l = inp
-            cache_l = None if self_k_l is None else (self_k_l, self_v_l)
+        def body(x, lp, cross_k_l, cross_v_l, kv_cache=None, layer=None):
             a, new = attention_block(
                 lp.self_attn, rmsnorm(x, lp.norm1, cfg.norm_eps), cfg,
-                kv_cache=cache_l, cache_index=index)
+                kv_cache=kv_cache, layer=layer, cache_index=index)
             x = x + a
             c, _ = attention_block(
                 lp.cross_attn, rmsnorm(x, lp.norm2, cfg.norm_eps), cfg,
@@ -562,15 +549,20 @@ class EncDecLM:
 
         if kv_cache is None:
             def body_nc(x, inp):
-                lp, cross_k_l, cross_v_l = inp
-                return body(x, (lp, cross_k_l, cross_v_l, None, None))
+                return body(x, *inp)[0], None
             h, _ = jax.lax.scan(_maybe_remat(body_nc, cfg), h,
                                 (params["dec_layers"], ck, cv))
             new_cache = None
         else:
-            h, (nk, nv) = jax.lax.scan(
-                body, h, (params["dec_layers"], ck, cv,
-                          kv_cache[0], kv_cache[1]))
+            def body_c(carry, inp):
+                x, sk, sv = carry
+                lp, cross_k_l, cross_v_l, layer = inp
+                x, (sk, sv) = body(x, lp, cross_k_l, cross_v_l,
+                                   kv_cache=(sk, sv), layer=layer)
+                return (x, sk, sv), None
+            (h, nk, nv), _ = jax.lax.scan(
+                body_c, (h, kv_cache[0], kv_cache[1]),
+                (params["dec_layers"], ck, cv, jnp.arange(cfg.n_layers)))
             new_cache = (nk, nv)
         return rmsnorm(h, params["final_norm"], cfg.norm_eps), new_cache
 
@@ -588,10 +580,9 @@ class EncDecLM:
 
     def init_cache(self, batch: int, max_len: int):
         cfg = self.cfg
-        kshape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.hd)
         cross = (cfg.n_layers, batch, cfg.enc_len, cfg.n_kv_heads, cfg.hd)
-        return {"k": jnp.zeros(kshape, cfg.compute_dtype),
-                "v": jnp.zeros(kshape, cfg.compute_dtype),
+        return {"k": kv_zeros(cfg, cfg.n_layers, batch, max_len),
+                "v": kv_zeros(cfg, cfg.n_layers, batch, max_len),
                 "cross_k": jnp.zeros(cross, cfg.compute_dtype),
                 "cross_v": jnp.zeros(cross, cfg.compute_dtype),
                 "index": jnp.zeros((batch,), jnp.int32)}

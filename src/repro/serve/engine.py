@@ -3,8 +3,9 @@ data plane (the vLLM role in the paper, §4 "Containerized Workers"),
 reimplemented TPU-native in JAX.
 
 Adaptation (see DESIGN.md §3): instead of paged KV with pointer chasing, a
-SLOT-BASED contiguous cache — (L, n_slots, max_len, H_kv, hd) — with a free-
-slot allocator and per-slot valid lengths. Continuous batching = admit new
+SLOT-BASED contiguous cache — (L, n_slots, H_kv, hd, max_len), positions
+minor so hd 64/96 is not padded to 128 lanes, owned by the models — with a
+free-slot allocator and per-slot valid lengths. Continuous batching = admit new
 requests into free slots between decode steps; one jitted decode step always
 runs over all slots (inactive slots are masked by their length), so the
 compiled graph is static while the request mix churns — exactly the

@@ -80,45 +80,57 @@ def test_flash_attention_extreme_values():
 
 
 # ---------------------------------------------------------------------------
-# decode attention — ragged lengths sweep
+# decode attention — ragged lengths sweep over the stacked positions-minor
+# cache (L, B, Hkv, hd, S), read at one layer
 # ---------------------------------------------------------------------------
+def stacked_kv(key, L, B, S, Hkv, hd, dtype):
+    k = rand(jax.random.fold_in(key, 1), (L, B, Hkv, hd, S), dtype)
+    v = rand(jax.random.fold_in(key, 2), (L, B, Hkv, hd, S), dtype)
+    return k, v
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,S,Hq,Hkv,hd,bk", [
-    (1, 64, 4, 4, 32, 32),
-    (3, 128, 8, 2, 32, 32),
-    (2, 256, 16, 4, 64, 128),
-    (4, 64, 4, 1, 16, 16),
+@pytest.mark.parametrize("L,layer,B,S,Hq,Hkv,hd,bk", [
+    (1, 0, 1, 64, 4, 4, 32, 32),
+    (3, 1, 3, 128, 8, 2, 32, 32),
+    (2, 1, 2, 256, 16, 4, 64, 128),
+    (4, 3, 4, 64, 4, 1, 16, 16),
 ])
-def test_decode_attention_sweep(B, S, Hq, Hkv, hd, bk, dtype):
+def test_decode_attention_sweep(L, layer, B, S, Hq, Hkv, hd, bk, dtype):
     key = jax.random.key(hash((B, S, Hq)) % 2**31)
     q = rand(key, (B, Hq, hd), dtype)
-    k = rand(jax.random.fold_in(key, 1), (B, S, Hkv, hd), dtype)
-    v = rand(jax.random.fold_in(key, 2), (B, S, Hkv, hd), dtype)
+    k, v = stacked_kv(key, L, B, S, Hkv, hd, dtype)
     lengths = jax.random.randint(jax.random.fold_in(key, 3), (B,), 1, S + 1)
-    out = decode_attention(q, k, v, lengths, blk_k=bk, interpret=True)
-    want = ref.decode_attention_ref(q, k, v, lengths)
+    out = decode_attention(q, k, v, jnp.int32(layer), lengths, blk_k=bk,
+                           interpret=True)
+    want = ref.decode_attention_ref(q, k, v, layer, lengths)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
 
 
-@given(st.lists(st.integers(1, 64), min_size=1, max_size=4))
+@given(st.lists(st.integers(1, 64), min_size=1, max_size=4),
+       st.integers(0, 2))
 @settings(max_examples=15, deadline=None)
-def test_decode_attention_ragged_property(lens):
-    B, S, Hq, Hkv, hd = len(lens), 64, 4, 2, 16
+def test_decode_attention_ragged_property(lens, layer):
+    L, B, S, Hq, Hkv, hd = 3, len(lens), 64, 4, 2, 16
     key = jax.random.key(sum(lens))
     q = rand(key, (B, Hq, hd), jnp.float32)
-    k = rand(jax.random.fold_in(key, 1), (B, S, Hkv, hd), jnp.float32)
-    v = rand(jax.random.fold_in(key, 2), (B, S, Hkv, hd), jnp.float32)
+    k, v = stacked_kv(key, L, B, S, Hkv, hd, jnp.float32)
     lengths = jnp.asarray(lens, jnp.int32)
-    out = decode_attention(q, k, v, lengths, blk_k=16, interpret=True)
-    want = ref.decode_attention_ref(q, k, v, lengths)
+    out = decode_attention(q, k, v, jnp.int32(layer), lengths, blk_k=16,
+                           interpret=True)
+    want = ref.decode_attention_ref(q, k, v, layer, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=3e-5, atol=3e-5)
-    # INVARIANT: cache contents past length[b] must not affect the output
-    k2 = k.at[:, -1].set(99.0)
+    # INVARIANT: cache contents past length[b], and every other layer,
+    # must not affect the output
+    k2 = k.at[:, :, :, :, -1].set(99.0)
+    k2 = k2.at[(layer + 1) % L].set(-99.0)
     masked_same = decode_attention(
-        q, k2, v, jnp.minimum(lengths, S - 1), blk_k=16, interpret=True)
-    want2 = ref.decode_attention_ref(q, k2, v, jnp.minimum(lengths, S - 1))
+        q, k2, v, jnp.int32(layer), jnp.minimum(lengths, S - 1), blk_k=16,
+        interpret=True)
+    want2 = ref.decode_attention_ref(q, k, v, layer,
+                                     jnp.minimum(lengths, S - 1))
     np.testing.assert_allclose(np.asarray(masked_same), np.asarray(want2),
                                rtol=3e-5, atol=3e-5)
 

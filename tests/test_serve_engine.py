@@ -96,6 +96,70 @@ def test_greedy_is_deterministic(served):
     assert once() == once()      # deterministic -> publishable by content hash
 
 
+def full_forward_kv(cfg, params, tokens):
+    """Each layer's K (RoPE applied) and V over ``tokens`` (T,), from a
+    teacher-forced cacheless forward: (L, T, Hkv, hd) each."""
+    from repro.models.attention import attention_block
+    from repro.models.common import (apply_rope, embed_lookup, rmsnorm,
+                                     rope_angles)
+    from repro.models.ffn import swiglu
+    T = len(tokens)
+    x = embed_lookup(params["embed"], jnp.asarray(tokens)[None],
+                     cfg.compute_dtype)
+    sin, cos = rope_angles(jnp.arange(T)[None], cfg.hd, cfg.rope_theta)
+    ks, vs = [], []
+    for layer in range(cfg.n_layers):
+        lp = jax.tree.map(lambda a: a[layer], params["layers"])
+        xn = rmsnorm(x, lp.norm1, cfg.norm_eps)
+        k = (xn @ lp.attn.wk).reshape(1, T, cfg.n_kv_heads, cfg.hd)
+        ks.append(apply_rope(k, sin, cos)[0])
+        vs.append((xn @ lp.attn.wv).reshape(T, cfg.n_kv_heads, cfg.hd))
+        a, _ = attention_block(lp.attn, xn, cfg)
+        x = x + a
+        x = x + swiglu(lp.mlp, rmsnorm(x, lp.norm2, cfg.norm_eps),
+                       cfg.compute_dtype)
+    return jnp.stack(ks), jnp.stack(vs)
+
+
+def test_engine_writes_each_slots_rows_in_place(served):
+    """The stacked (L, slots, Hkv, hd, positions) cache after a prefill of
+    T tokens into slot s and n decode steps: the slot's first T+n
+    positions hold the K and V a full forward projects, its later
+    positions are as the prefill left them (zero), and every other slot
+    keeps what it held past the n rows the steps wrote at its own
+    index."""
+    cfg, model, params = served
+    T, n, s, max_len = 7, 5, 1, 32
+    eng = ServingEngine(model, params, n_slots=3, max_len=max_len)
+    keys = jax.random.split(jax.random.key(7), 2)
+    before = {name: np.asarray(jax.random.normal(
+        key, eng.cache[name].shape, eng.cache[name].dtype))
+        for name, key in zip(("k", "v"), keys)}
+    # committed like the engine's own cache (the prefill donates it)
+    eng.cache = jax.device_put({**eng.cache, **before},
+                               eng.cache["index"].devices().pop())
+    eng.free_slots = [s] + [i for i in eng.free_slots if i != s]
+    rng = np.random.default_rng(8)
+    req = Request(rng.integers(0, cfg.vocab_size, T).astype(np.int32),
+                  max_new_tokens=n + 4)
+    eng.submit(req)
+    for _ in range(n):
+        eng.step()
+    assert req.slot == s and int(eng.cache["index"][s]) == T + n
+    want_k, want_v = full_forward_kv(
+        cfg, params, np.concatenate([req.prompt, req.generated[:n]]))
+    for name, want in (("k", want_k), ("v", want_v)):
+        got = np.asarray(eng.cache[name])
+        np.testing.assert_allclose(
+            got[:, s, :, :, :T + n], np.moveaxis(np.asarray(want), 1, -1),
+            rtol=2e-4, atol=2e-4)
+        assert not got[:, s, :, :, T + n:].any()
+        others = [i for i in range(eng.n_slots) if i != s]
+        np.testing.assert_array_equal(
+            got[:, others, :, :, n:],
+            before[name][:, others, :, :, n:])
+
+
 # ----------------------------------------------------- counters and spans --
 def test_counters_after_a_closed_batch(served):
     """``stats()`` after a closed batch: one prefill per request, their
