@@ -10,9 +10,12 @@ window's. The window: closed batches back to back, each step timed on the
 host clock (a step ends in host reads of the sampled tokens, so the device
 has finished). Afterwards the program's state is freed and the float32
 reference reads a seeded sample of the finished requests, the longest
-prompt among them. With ``control`` the fp8 control stands in the
-program's place: the gap check reads the tokens the control puts first at
-the same positions, and a sound benchmark finds it not correct.
+prompt among them. The reference is the module the configuration file
+names (``"reference": "<module>"``, ``reference/<module>.py``), loaded
+before set-up; its ``WIDTHS`` are the widths the program is checked on.
+With ``control`` the fp8 control stands in the program's place: the gap
+check reads the tokens the control puts first at the same positions, and
+a sound benchmark finds it not correct.
 """
 from __future__ import annotations
 
@@ -21,7 +24,6 @@ import json
 import random
 import time
 
-import counts
 import schedule
 import suite
 from suite import NoChip, RunError
@@ -50,6 +52,7 @@ def run(cell: suite.Cell, seed: int, seconds: float, trace: bool,
         t_start: float, *, require_tpu: bool = True, fault: str = "",
         control: bool = False, log=print) -> dict:
     use_checkout(cell.root)
+    reference = suite.reference(cell)
     phases: dict[str, float] = {}
     import jax
     phases["import_jax"] = time.monotonic() - t_start
@@ -75,7 +78,7 @@ def run(cell: suite.Cell, seed: int, seconds: float, trace: bool,
     if require_tpu and ops._resolve(None) != "pallas":
         raise RunError("the engine fell back from the Pallas kernels")
     tr, config = cell.traffic, cell.config
-    cfg = program_config(config)
+    cfg = program_config(config, reference)
     model = build_model(cfg)
     with jax.default_device(dev):
         params = jax.jit(model.init)(jax.random.key(seed))
@@ -94,7 +97,6 @@ def run(cell: suite.Cell, seed: int, seconds: float, trace: bool,
         phases["warmed"] = time.monotonic() - t_start
         if trace:
             _annotate(engine)
-        m = counts.dims(config)
         peak = suite.peaks(dev.device_kind, cell.bench_dir) \
             if require_tpu else None
         batches = schedule.eval_batches(tr, vocab, seed,
@@ -170,10 +172,9 @@ def run(cell: suite.Cell, seed: int, seconds: float, trace: bool,
         del engine, params
         gc.collect()
         jax.clear_caches()
-        from reference.dense import gaps
         items = sample(finished, int(config["check"]["sample_requests"]),
                        seed)
-        ref = gaps(config, seed, items, control=control)
+        ref = reference.gaps(config, seed, items, control=control)
     window_s = t_end - t0
     tokens = window_tokens(steps)
     log("setup " + json.dumps({**phases, "window_open": setup_s}))
@@ -191,7 +192,7 @@ def run(cell: suite.Cell, seed: int, seconds: float, trace: bool,
               suite.check("logit_gap_max", gap,
                           float(config["check"]["logit_gap_limit"]))]
     rec = {"kind": "engine", "window_s": window_s, "steps": steps,
-           "traced_steps": traced, "trace": reduced, "dims": m,
+           "traced_steps": traced, "trace": reduced,
            "peak": peak, "config": config}
     return {"rec": rec, "e2e": {"setup_s": setup_s,
                                 "tokens_per_s": tokens / window_s},

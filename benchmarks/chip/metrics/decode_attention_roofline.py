@@ -15,7 +15,7 @@ def read(rec):
     steps = rec["steps"][a:b]
     if not k or not k["calls"] or not k["s"] or not steps:
         return None
-    m = rec["dims"]
+    m = counts.dims(rec["config"])
     least = 0.0
     for s in steps:
         fl, by = counts.decode_attention_call(m, s["lengths"])

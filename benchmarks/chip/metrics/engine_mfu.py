@@ -9,7 +9,7 @@ def read(rec):
     peak = rec.get("peak")
     if rec.get("kind") != "engine" or not peak or not rec["steps"]:
         return None
-    m = rec["dims"]
+    m = counts.dims(rec["config"])
     flops = 0.0
     for s in rec["steps"]:
         flops += sum(counts.prefill_flops(m, n) for n in s["prompts"])

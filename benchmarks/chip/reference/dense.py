@@ -31,14 +31,30 @@ import numpy as np
 
 HI = jax.lax.Precision.HIGHEST
 
+#: published ``config`` key -> the program's ``ArchConfig`` field that the
+#: width check holds equal to it (names only)
+WIDTHS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
+          "num_attention_heads": "n_heads",
+          "num_key_value_heads": "n_kv_heads",
+          "intermediate_size": "d_ff", "vocab_size": "vocab_size",
+          "head_dim": "hd"}
+
+
+def published(c: dict) -> dict:
+    """The configuration's keys, a ``head_dim`` it leaves out taken as
+    ``hidden_size / num_attention_heads``, as the published model does."""
+    if c.get("head_dim") or not (c.get("hidden_size")
+                                 and c.get("num_attention_heads")):
+        return c
+    return {**c, "head_dim": c["hidden_size"] // c["num_attention_heads"]}
+
 
 def _dims(config: dict) -> dict:
-    c = config["config"]
+    c = published(config["config"])
     heads = int(c["num_attention_heads"])
     d = int(c["hidden_size"])
     return {"L": int(c["num_hidden_layers"]), "d": d, "hq": heads,
-            "hkv": int(c["num_key_value_heads"]),
-            "hd": int(c.get("head_dim") or d // heads),
+            "hkv": int(c["num_key_value_heads"]), "hd": int(c["head_dim"]),
             "ff": int(c["intermediate_size"]), "V": int(c["vocab_size"]),
             "theta": float(c.get("rope_theta", 10000.0)),
             "eps": float(c.get("rms_norm_eps", 1e-5))}

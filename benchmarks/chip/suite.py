@@ -4,7 +4,11 @@ files, the traffic mixes, the per-layer readers and the table of peaks.
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric lives in a file of its own under this directory:
 
-    configs/<config>.json     sizes, source, departures, correctness limit
+    configs/<config>.json     sizes, source, departures, correctness limit;
+                              its "reference" names reference/<module>.py
+    reference/<module>.py     the plain reference a configuration names, and
+                              the widths the program is checked on (the
+                              contract: ``reference/__init__.py``)
     traffic/<traffic>.json    the mix; its "driver" names drivers/<driver>.py
     metrics/<metric>.py       ``read(rec) -> float | None``; a metric named
                               ``base.suffix`` falls back to ``metrics/base.py``
@@ -50,6 +54,7 @@ class Cell:
     end_to_end: list[dict]
     per_layer: list[dict]
     root: Path
+    config_file: Path
     bench_dir: Path = HERE
 
 
@@ -68,7 +73,8 @@ def load_cell(name: str, root: Path | None = None,
                        f"(have {sorted(cells)})")
     w = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
-    config = json.loads((root / configs[w["config"]]["file"]).read_text())
+    config_file = root / configs[w["config"]]["file"]
+    config = json.loads(config_file.read_text())
     config.setdefault("name", w["config"])
     traffic = json.loads(
         (bench_dir / "traffic" / f"{w['traffic']}.json").read_text())
@@ -79,7 +85,30 @@ def load_cell(name: str, root: Path | None = None,
                             if _applies(m, name)],
                 per_layer=[m for m in spec["per_layer"]
                            if _applies(m, name)],
-                root=root, bench_dir=bench_dir)
+                root=root, config_file=config_file, bench_dir=bench_dir)
+
+
+def _load(path: Path, prefix: str):
+    mod_name = prefix + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference(cell: Cell):
+    """The reference module the cell's configuration names by its
+    ``reference`` key: ``reference/<module>.py``. A key or a module that is
+    not there is a ``RunError``."""
+    name = cell.config.get("reference")
+    if not isinstance(name, str) or not name:
+        raise RunError(f"{cell.config_file} names no reference module (its "
+                       f"\"reference\" key)")
+    path = cell.bench_dir / "reference" / f"{name}.py"
+    if name.startswith("_") or "/" in name or not path.is_file():
+        raise RunError(f"{cell.config_file} names reference {name!r}, and "
+                       f"there is no {path}")
+    return _load(path, "bench_reference_")
 
 
 def reader(metric: str, bench_dir: Path = HERE):
@@ -89,12 +118,7 @@ def reader(metric: str, bench_dir: Path = HERE):
     for stem in (metric, metric.split(".", 1)[0]):
         path = base / f"{stem}.py"
         if path.is_file():
-            mod_name = "bench_metric_" + stem.replace(".", "_").replace(
-                "-", "_")
-            spec = importlib.util.spec_from_file_location(mod_name, path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+            return _load(path, "bench_metric_").read
     raise FileNotFoundError(f"no reader for per-layer metric {metric!r} "
                             f"under {base}")
 

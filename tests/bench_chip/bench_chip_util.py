@@ -24,6 +24,7 @@ TINY = {
                "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256,
                "rms_norm_eps": 1e-05, "rope_theta": 500000.0},
     "reduced": [], "assumed": [],
+    "reference": "dense",
     "kernels": {"decode_attention": "decode_attention"},
     # at this width on the CPU the program reads 0.000-0.003 and the fp8
     # control 0.08-0.35 (eight seeds); planted faults read far above
@@ -80,6 +81,33 @@ def checkout(tmp: Path, *, metric_src: str | None = None) -> Path:
     }
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     return root
+
+
+def add_cell(root: Path, config_name: str, config: dict, *,
+             traffic: str = "tiny-engine",
+             references: dict[str, str] | None = None) -> str:
+    """Add a configuration and its engine cell to a checkout as files alone:
+    ``configs/<config_name>.json``, any ``reference/<module>.py`` given as
+    source, and BENCHMARK.json entries (the cell reports every metric that
+    ``tiny.engine`` does). Returns the cell's name."""
+    bench = root / "benchmarks" / "chip"
+    rel = f"benchmarks/chip/configs/{config_name}.json"
+    (root / rel).write_text(json.dumps(config))
+    for module, src in (references or {}).items():
+        (bench / "reference" / f"{module}.py").write_text(src)
+    cell = f"{config_name}.engine"
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": config_name,
+                            "source": "https://example.org/" + config_name,
+                            "file": rel, "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": cell, "config": config_name,
+                              "traffic": traffic, "chips": 1,
+                              "why": "test"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "tiny.engine" in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return cell
 
 
 def run(root: Path, cell: str, **kw) -> dict:

@@ -27,3 +27,29 @@ def test_broken_timed_path_is_not_correct(root, fault):
     assert not res["correct"]
     gap = res["checks"][-1]
     assert gap["name"] == "logit_gap_max" and not gap["ok"]
+
+
+@pytest.fixture
+def donated_decode(monkeypatch):
+    """Every ``ServingEngine`` donates its cache to the decode step, as a
+    program that stops copying the cache would."""
+    import jax
+    from repro.serve import engine as serve_engine
+    init = serve_engine.ServingEngine.__init__
+
+    def donating(self, *a, **k):
+        init(self, *a, **k)
+        self._decode_step = jax.jit(self._decode_step.__wrapped__,
+                                    donate_argnums=(2,))
+    monkeypatch.setattr(serve_engine.ServingEngine, "__init__", donating)
+
+
+@pytest.mark.parametrize("fault", ["", "token", "stale", "half"])
+def test_faults_hold_under_a_donated_decode_cache(root, donated_decode,
+                                                  fault):
+    """With the decode cache donated, a sound run is correct and each
+    fault ends not correct, not in a deleted array."""
+    res = u.run(root, "tiny.engine", seconds=1.0, fault=fault)
+    gap = res["checks"][-1]
+    assert gap["name"] == "logit_gap_max"
+    assert res["correct"] == (not fault) == gap["ok"], res["checks"]

@@ -15,6 +15,7 @@ import pytest
 
 import bench_chip_util as u
 from program import program_config, use_checkout
+from reference import dense
 from reference.dense import Weights, gaps, logits_at
 
 use_checkout(u.REPO)
@@ -23,7 +24,7 @@ SEED = 2**31 + 77
 
 def program(cfg_file: dict, f32: bool = False):
     from repro.models.transformer import build_model
-    cfg = program_config(cfg_file)
+    cfg = program_config(cfg_file, dense)
     if f32:
         cfg = dataclasses.replace(cfg, param_dtype=jnp.float32,
                                   compute_dtype=jnp.float32)

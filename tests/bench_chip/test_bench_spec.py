@@ -5,11 +5,13 @@ from __future__ import annotations
 
 import json
 import re
+from types import SimpleNamespace
 
 import pytest
 
 import bench_chip_util as u
 import suite
+from program import checked_widths
 
 SPEC = json.loads((u.REPO / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -45,14 +47,63 @@ def test_every_cell_finds_its_files(cell):
         assert moved, f"{m['name']} moves a metric {cell} does not report"
 
 
+#: a width by the contract's rule, never cut whatever the architecture:
+#: hidden, intermediate, latent, state and projection sizes, head sizes
+#: and counts, keys ending in _dim or _rank, expansion factors, experts per
+#: token. Depth, expert count and vocabulary are scale, and may be cut.
+WIDTH = re.compile(r"(^|_)(dim|rank|hidden_size|intermediate_size|"
+                   r"latent_size|state_size|proj\w*_size|head_size|heads|"
+                   r"expand|expansion_factor|experts_per_tok)$|^d_")
+
+
+def holds_published_widths(entry: dict, cfg: dict) -> None:
+    """A configuration file as BENCHMARK.json lists it: the same cuts and
+    source, no width cut, a reference module that is there, and every key
+    that module checks present (a cut key at its cut value)."""
+    assert cfg["reduced"] == entry["reduced"]
+    assert cfg["source"] == entry["source"]
+    assert not [k for k in entry["reduced"] if WIDTH.search(k)]
+    assert (u.BENCH / "reference" / f"{cfg['reference']}.py").is_file()
+    ref = suite.reference(SimpleNamespace(
+        config=cfg, config_file=entry["file"], bench_dir=u.BENCH))
+    assert set(checked_widths(cfg, ref)) == set(ref.WIDTHS)
+
+
 def test_configuration_files_hold_their_published_widths():
     for c in SPEC["configs"]:
-        cfg = json.loads((u.REPO / c["file"]).read_text())
-        assert cfg["reduced"] == c["reduced"]
-        assert cfg["source"] == c["source"]
-        widths = {"hidden_size", "intermediate_size", "num_attention_heads",
-                  "num_key_value_heads", "head_dim"}
-        assert not widths & set(c["reduced"])
+        holds_published_widths(c, json.loads((u.REPO / c["file"]).read_text()))
+
+
+def test_a_depth_cut_configuration_holds_its_published_widths():
+    """Cut to fewer layers, a configuration names ``num_hidden_layers`` in
+    ``reduced`` and is still checked on it."""
+    cfg = json.loads(json.dumps(u.TINY))
+    cfg["config"]["num_hidden_layers"] = 1
+    cfg["reduced"] = ["num_hidden_layers"]
+    cfg["source"] = "https://example.org/tiny-cut"
+    entry = {"file": "tiny-cut.json", "source": cfg["source"],
+             "reduced": cfg["reduced"]}
+    holds_published_widths(entry, cfg)
+    with pytest.raises(AssertionError):
+        holds_published_widths({**entry, "reduced": ["hidden_size"]},
+                               {**cfg, "reduced": ["hidden_size"]})
+
+
+@pytest.mark.parametrize("key,width", [
+    # the next configuration's widths (Kimi-K2-Instruct) ...
+    ("hidden_size", True), ("intermediate_size", True),
+    ("moe_intermediate_size", True), ("num_attention_heads", True),
+    ("num_key_value_heads", True), ("head_dim", True),
+    ("q_lora_rank", True), ("kv_lora_rank", True),
+    ("qk_nope_head_dim", True), ("qk_rope_head_dim", True),
+    ("v_head_dim", True), ("num_experts_per_tok", True),
+    ("ssm_state_size", True), ("expand", True), ("d_state", True),
+    # ... and the scale it is cut in
+    ("num_hidden_layers", False), ("n_routed_experts", False),
+    ("vocab_size", False), ("max_position_embeddings", False),
+])
+def test_widths_are_told_from_scale_by_name(key, width):
+    assert bool(WIDTH.search(key)) == width
 
 
 def test_peaks_are_keyed_by_device_kind_and_unknown_kinds_refused():
