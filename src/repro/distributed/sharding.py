@@ -20,12 +20,12 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.logical import gspmd
-from repro.models.attention import AttnParams
+from repro.models.attention import AttnParams, MLAParams
 from repro.models.common import ArchConfig
 from repro.models.ffn import MLPParams, MoEParams
 from repro.models.mamba2 import Mamba2Params
 from repro.models.transformer import (DecLayer, DenseLayer, EncLayer,
-                                      MoELayer, SSMLayer)
+                                      LatentLayer, MoELayer, SSMLayer)
 
 
 def _axis(mesh, name: str) -> int:
@@ -50,6 +50,19 @@ def attn_specs(l=None) -> AttnParams:
     )
 
 
+def mla_specs(l=None) -> MLAParams:
+    """Down-projections FSDP over data; the per-head up-projections and
+    the output projection split heads over model."""
+    lead = (None,) if l is not None else ()
+    return MLAParams(
+        wq_a=P(*lead, "data", None), q_norm=P(*lead, None),
+        wq_b=P(*lead, None, "model"),
+        wkv_a=P(*lead, "data", None), kv_norm=P(*lead, None),
+        wkv_b=P(*lead, None, "model"),
+        wo=P(*lead, "model", "data"),
+    )
+
+
 def mlp_specs(l=None) -> MLPParams:
     lead = (None,) if l is not None else ()
     return MLPParams(
@@ -61,6 +74,7 @@ def mlp_specs(l=None) -> MLPParams:
 
 def moe_specs(cfg: ArchConfig, mesh, l=None) -> MoEParams:
     lead = (None,) if l is not None else ()
+    bias = P(*lead, None) if cfg.router == "sigmoid" else None
     # experts over model (EP) + Megatron col/row split of each expert's MLP
     # over data: the d-dim contraction stays LOCAL (no weight all-gather —
     # the naive d-over-data layout all-gathered 1.1 TB/step on kimi decode);
@@ -75,6 +89,7 @@ def moe_specs(cfg: ArchConfig, mesh, l=None) -> MoEParams:
             w_up=P(*lead, e_axis, None, None),
             w_down=P(*lead, e_axis, None, None),
             shared=mlp_specs(l) if cfg.n_shared_experts else None,
+            bias=bias,
         )
     e_axis: object = "model"
     if "pod" in mesh.axis_names and cfg.n_experts % (
@@ -84,7 +99,7 @@ def moe_specs(cfg: ArchConfig, mesh, l=None) -> MoEParams:
     # slab is small (qwen-class), keep d/ff unsharded so the expert einsum
     # is fully local; big models use the EP path instead
     per_shard_gb = (cfg.n_experts / _axis(mesh, "model") * cfg.d_model
-                    * cfg.d_ff * 3 * 2 * (cfg.n_layers)) / 1e9
+                    * cfg.expert_ff * 3 * 2 * (cfg.n_layers)) / 1e9
     if per_shard_gb <= 4.0:
         return MoEParams(
             router=P(*lead, None, None),
@@ -92,6 +107,7 @@ def moe_specs(cfg: ArchConfig, mesh, l=None) -> MoEParams:
             w_up=P(*lead, e_axis, None, None),
             w_down=P(*lead, e_axis, None, None),
             shared=mlp_specs(l) if cfg.n_shared_experts else None,
+            bias=bias,
         )
     return MoEParams(
         router=P(*lead, None, None),
@@ -99,6 +115,7 @@ def moe_specs(cfg: ArchConfig, mesh, l=None) -> MoEParams:
         w_up=P(*lead, e_axis, "data", None),
         w_down=P(*lead, e_axis, "data", None),
         shared=mlp_specs(l) if cfg.n_shared_experts else None,
+        bias=bias,
     )
 
 
@@ -141,6 +158,18 @@ def param_specs(cfg: ArchConfig, mesh):
             "layers": MoELayer(attn=attn_specs(l=0),
                                moe=moe_specs(cfg, mesh, l=0),
                                norm1=_norm(0), norm2=_norm(0)),
+            "final_norm": _norm(),
+            "lm_head": P("data", "model"),
+        }
+    if fam == "mla_moe":
+        return {
+            "embed": P("model", "data"),
+            "dense_layers": LatentLayer(attn=mla_specs(l=0),
+                                        ffn=mlp_specs(l=0),
+                                        norm1=_norm(0), norm2=_norm(0)),
+            "layers": LatentLayer(attn=mla_specs(l=0),
+                                  ffn=moe_specs(cfg, mesh, l=0),
+                                  norm1=_norm(0), norm2=_norm(0)),
             "final_norm": _norm(),
             "lm_head": P("data", "model"),
         }
@@ -210,6 +239,8 @@ def cache_specs(cfg: ArchConfig, mesh):
         return {"k": _kv_spec(cfg, mesh),
                 "v": _kv_spec(cfg, mesh),
                 "index": P(b)}
+    if fam == "mla_moe":      # (L, B, kv_lora + rope, S): slots over data
+        return {"latent": P(None, b, None, None), "index": P(b)}
     if fam == "ssm":
         return {"state": _mamba_state_spec(cfg, mesh, lead=1),
                 "index": P(b)}

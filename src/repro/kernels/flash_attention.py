@@ -6,7 +6,9 @@ accumulation on the MXU, one (blk_q, hd) output tile written at the last kv
 step. Causal block-skip: fully-masked kv blocks are not computed.
 
 Block shapes default to (128, 128) x hd — MXU-aligned; hd in {64..128}
-pads to the lane width automatically.
+pads to the lane width automatically. V may be narrower than Q and K (MLA
+prefill: q/k 192 wide, v 128), and the softmax scale may be given (MLA's
+YaRN factor).
 """
 from __future__ import annotations
 
@@ -68,20 +70,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "blk_q", "blk_k",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "blk_q",
+                                             "blk_k", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, blk_q: int = 128, blk_k: int = 128,
+                    causal: bool = True, scale: float | None = None,
+                    blk_q: int = 128, blk_k: int = 128,
                     interpret: bool = False) -> jax.Array:
-    """q: (B, T, Hq, hd); k/v: (B, S, Hkv, hd) -> (B, T, Hq, hd)."""
+    """q/k: (B, T|S, Hq|Hkv, hd); v: (B, S, Hkv, dv) -> (B, T, Hq, dv).
+    ``scale`` defaults to 1/sqrt(hd)."""
     B, T, Hq, hd = q.shape
     _, S, Hkv, _ = k.shape
+    dv = v.shape[-1]
     g = Hq // Hkv
     blk_q = min(blk_q, T)
     blk_k = min(blk_k, S)
     assert T % blk_q == 0 and S % blk_k == 0
     n_q, n_k = T // blk_q, S // blk_k
-    scale = 1.0 / (hd ** 0.5)
+    scale = 1.0 / (hd ** 0.5) if scale is None else scale
 
     # layout: heads-major so each (b, h) pair owns contiguous (T, hd) tiles
     qt = q.transpose(0, 2, 1, 3)          # (B, Hq, T, hd)
@@ -99,14 +104,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec((None, None, blk_k, hd),
                          lambda b, h, qi, ki, g=g: (b, h // g, ki, 0)),
-            pl.BlockSpec((None, None, blk_k, hd),
+            pl.BlockSpec((None, None, blk_k, dv),
                          lambda b, h, qi, ki, g=g: (b, h // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((None, None, blk_q, hd),
+        out_specs=pl.BlockSpec((None, None, blk_q, dv),
                                lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, T, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, T, dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((blk_q, hd), jnp.float32),
+            pltpu.VMEM((blk_q, dv), jnp.float32),
             pltpu.VMEM((blk_q,), jnp.float32),
             pltpu.VMEM((blk_q,), jnp.float32),
         ],
@@ -115,4 +120,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                  "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)      # back to (B, T, Hq, hd)
+    return out.transpose(0, 2, 1, 3)      # back to (B, T, Hq, dv)

@@ -10,15 +10,20 @@ NEG_INF = -1e30
 
 
 def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                        causal: bool = True) -> jax.Array:
-    """q: (B, T, Hq, hd); k/v: (B, S, Hkv, hd); GQA by head broadcast."""
+                        causal: bool = True,
+                        scale: float | None = None) -> jax.Array:
+    """q: (B, T, Hq, hd); k: (B, S, Hkv, hd); v: (B, S, Hkv, dv); GQA by
+    head broadcast; ``scale`` defaults to 1/sqrt(hd)."""
     from repro.distributed.logical import constrain
     B, T, Hq, hd = q.shape
     _, S, Hkv, _ = k.shape
+    dv = v.shape[-1]
     g = Hq // Hkv
     qg = q.reshape(B, T, Hkv, g, hd).astype(jnp.float32)
     kf = k.astype(jnp.float32)
-    scores = jnp.einsum("bthgd,bshd->bhgts", qg, kf) / jnp.sqrt(float(hd))
+    scores = jnp.einsum("bthgd,bshd->bhgts", qg, kf)
+    scores = scores / jnp.sqrt(float(hd)) if scale is None \
+        else scores * scale
     if T > 1:
         # memory control under GSPMD (no-op without an installed policy):
         # shard the S^2 tensor's query dim — see models/attention.py note
@@ -28,7 +33,7 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scores = jnp.where(mask[None, None, None], scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgts,bshd->bthgd", p, v.astype(jnp.float32))
-    return out.reshape(B, T, Hq, hd).astype(q.dtype)
+    return out.reshape(B, T, Hq, dv).astype(q.dtype)
 
 
 def decode_attention_ref(q: jax.Array, k_cache: jax.Array,
@@ -49,6 +54,21 @@ def decode_attention_ref(q: jax.Array, k_cache: jax.Array,
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgs,bhds->bhgd", p, v.astype(jnp.float32))
     return out.reshape(B, Hq, hd).astype(q.dtype)
+
+
+def mla_decode_attention_ref(q: jax.Array, cache: jax.Array,
+                             layer: jax.Array, lengths: jax.Array, *,
+                             scale: float, rank: int) -> jax.Array:
+    """Absorbed MLA decode. q: (B, H, rank + rope); cache: the stacked
+    latent cache (L, B, rank + rope, S), read at ``layer``; lengths: (B,)
+    valid prefix. Returns (B, H, rank), the weighted sum of the cache's
+    first ``rank`` rows."""
+    c = cache[layer].astype(jnp.float32)                    # (B, D, S)
+    s = jnp.einsum("bhd,bds->bhs", q.astype(jnp.float32), c) * scale
+    valid = jnp.arange(c.shape[-1])[None, :] < lengths[:, None]
+    s = jnp.where(valid[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhs,brs->bhr", p, c[:, :rank]).astype(q.dtype)
 
 
 def ssd_ref(u: jax.Array, loga: jax.Array, Bm: jax.Array, Cm: jax.Array,

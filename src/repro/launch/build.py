@@ -33,6 +33,18 @@ def active_params(cfg: ArchConfig) -> float:
     """Per-token active parameter count (MoE: top_k + shared experts)."""
     d, hd = cfg.d_model, cfg.hd
     attn = d * hd * (2 * cfg.n_heads + 2 * cfg.n_kv_heads)
+    if cfg.family == "mla_moe":
+        h, ql, kl = cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+        mla = (d * ql + ql * h * cfg.qk_head_dim
+               + d * (kl + cfg.qk_rope_dim)
+               + kl * h * (cfg.qk_nope_dim + cfg.v_head_dim)
+               + h * cfg.v_head_dim * d)
+        dense = 3 * d * cfg.d_ff
+        moe = 3 * d * cfg.expert_ff * (cfg.top_k + cfg.n_shared_experts) \
+            + d * cfg.n_experts
+        n_moe = cfg.n_layers - cfg.n_dense_layers
+        return cfg.n_layers * mla + cfg.n_dense_layers * dense \
+            + n_moe * moe + 2 * cfg.vocab_size * d
     if cfg.family == "moe":
         ffn = 3 * d * cfg.d_ff * (cfg.top_k + cfg.n_shared_experts)
         ffn += d * cfg.n_experts                    # router
@@ -131,7 +143,7 @@ def build_cell(arch: str, shape_id: str, mesh, *, grad_accum: int = 1,
     from dataclasses import replace
     cfg = get_config(arch)
     logical.install(mesh)     # activation-sharding policy for trace time
-    if cfg.family == "moe":
+    if cfg.family in ("moe", "mla_moe"):
         import math as _m
         shards = _m.prod(mesh.shape[a] for a in mesh.axis_names
                          if a in ("pod", "data"))

@@ -6,7 +6,8 @@ scan-over-layers so compile time is O(1) in depth — essential for the
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field, replace
+import math
+from dataclasses import dataclass, replace
 from typing import Any
 
 import jax
@@ -17,7 +18,8 @@ import numpy as np
 @dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                  # dense | moe | ssm | hybrid | encdec | vlm
+    family: str                  # dense | moe | mla_moe | ssm | hybrid |
+                                 # encdec | vlm
     n_layers: int
     d_model: int
     n_heads: int                 # query heads (0 for attention-free)
@@ -33,6 +35,25 @@ class ArchConfig:
     moe_groups: int = 1        # dispatch groups == number of batch shards
     moe_impl: str = "gspmd"    # "gspmd" (grouped dispatch) | "ep" (a2a)
     moe_pad_experts: int = 0   # EP: experts padded to a multiple of ep_size
+    moe_d_ff: int = 0          # routed/shared expert width (0 => d_ff)
+    n_dense_layers: int = 0    # leading dense layers ahead of the MoE scan
+    # router: "softmax" (top-k of softmax, renormalised) | "sigmoid"
+    # (DeepSeek-V3 noaux_tc: sigmoid scores plus a bias pick the top-k, the
+    # unbiased scores weight them, normalised, times router_scale)
+    router: str = "softmax"
+    router_scale: float = 1.0     # routed_scaling_factor
+    router_bias_std: float = 0.0  # seeded e_score_correction_bias, N(0, std)
+    # the experts held here (model-configs §4): experts expert_lo ..
+    # expert_lo + n_held_experts - 1 of n_experts, run by the dropless
+    # held_experts_block; 0 => all of them, by moe_impl
+    expert_lo: int = 0
+    n_held_experts: int = 0
+    # --- MLA (latent attention, DeepSeek-V3 form); kv_lora_rank > 0 ---
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # --- SSM (mamba2 / SSD) ---
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -48,6 +69,13 @@ class ArchConfig:
     n_patches: int = 0           # image patch positions (stub frontend)
     # --- common ---
     rope_theta: float = 10000.0
+    # YaRN (DeepSeek-V3 form); rope_factor 1 => plain RoPE
+    rope_factor: float = 1.0
+    rope_original_max: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     norm_eps: float = 1e-5
     param_dtype: Any = jnp.bfloat16
     compute_dtype: Any = jnp.bfloat16
@@ -57,6 +85,18 @@ class ArchConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
+
+    @property
+    def expert_ff(self) -> int:         # routed/shared expert width
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_held_experts or self.n_experts
+
+    @property
+    def qk_head_dim(self) -> int:       # MLA query/key width
+        return self.qk_nope_dim + self.qk_rope_dim
 
     @property
     def d_inner(self) -> int:           # mamba2 inner width
@@ -88,7 +128,20 @@ class ArchConfig:
         if self.attn_every:
             base["attn_every"] = 2
             base["n_layers"] = 4
+        if self.kv_lora_rank:
+            base.update(q_lora_rank=48, kv_lora_rank=32, qk_nope_dim=16,
+                        qk_rope_dim=8, v_head_dim=16)
+        if self.moe_d_ff:
+            base["moe_d_ff"] = 64
+        if self.n_held_experts:
+            base.update(expert_lo=0,
+                        n_held_experts=min(self.n_held_experts, 2))
+        if self.rope_original_max:
+            # small enough that the tests' positions pass it
+            base["rope_original_max"] = 16
         base.update(overrides)
+        base.setdefault("n_dense_layers", min(self.n_dense_layers,
+                                              base["n_layers"] - 1))
         return replace(self, **base)
 
 
@@ -101,12 +154,52 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-5) -> jax.Array:
 
 
 def rope_angles(positions: jax.Array, head_dim: int, theta: float,
+                cfg: ArchConfig | None = None,
                 ) -> tuple[jax.Array, jax.Array]:
-    """(sin, cos) of shape (..., head_dim/2) for given integer positions."""
+    """(sin, cos) of shape (..., head_dim/2) for given integer positions;
+    with a ``cfg`` whose ``rope_factor`` exceeds 1, YaRN's frequencies and
+    its cos/sin factor."""
     inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
                                       dtype=jnp.float32) / head_dim))
+    scale = 1.0
+    if cfg is not None and cfg.rope_factor > 1:
+        inv = yarn_inv_freq(head_dim, theta, cfg)
+        scale = yarn_mscale(cfg.rope_factor, cfg.yarn_mscale) / \
+            yarn_mscale(cfg.rope_factor, cfg.yarn_mscale_all_dim)
     ang = positions[..., None].astype(jnp.float32) * inv
-    return jnp.sin(ang), jnp.cos(ang)
+    return jnp.sin(ang) * scale, jnp.cos(ang) * scale
+
+
+# -- YaRN, as DeepSeek-V3's modeling_deepseek.py computes it ---------------
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, cfg: ArchConfig) -> np.ndarray:
+    """Inverse frequencies of a ``dim``-wide rotary part: the original
+    ones below the correction range, those divided by ``rope_factor``
+    above it, a linear ramp between."""
+    def corr_dim(rot):
+        return dim * math.log(cfg.rope_original_max / (rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos = np.arange(0, dim, 2, dtype=np.float32) / dim
+    extra = (1.0 / theta ** pos).astype(np.float32)
+    inter = (1.0 / (cfg.rope_factor * theta ** pos)).astype(np.float32)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp            # 1 => the original (extrapolated) freq
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """1/sqrt(q/k width), times YaRN's mscale(mscale_all_dim) squared."""
+    m = yarn_mscale(cfg.rope_factor, cfg.yarn_mscale_all_dim) \
+        if cfg.yarn_mscale_all_dim else 1.0
+    return cfg.qk_head_dim ** -0.5 * m * m
 
 
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:

@@ -14,8 +14,9 @@ independent of expert-weight size. The GSPMD alternatives measured in the
 dry-run iteration log moved 0.9–16 PB/step on kimi-k2 (weight all-gathers);
 this path moves ~0.12 PB-equivalent... see EXPERIMENTS.md §Perf.
 
-Semantics are identical to ffn.moe_block (same routing, same capacity-drop
-policy per source shard) — tests/test_distributed.py checks equivalence on
+Semantics are identical to ffn.moe_block (the same ``route``, softmax or
+sigmoid noaux_tc; the same capacity-drop policy per source shard, which a
+dropless layer does not share) — tests/test_distributed.py checks equivalence on
 an 8-device host platform.
 """
 from __future__ import annotations
@@ -25,7 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .common import ArchConfig
-from .ffn import MoEParams, swiglu
+from .ffn import MoEParams, route, swiglu
 
 
 def ep_axes(mesh) -> tuple[str, ...]:
@@ -63,18 +64,11 @@ def moe_block_ep(p: MoEParams, x: jax.Array, cfg: ArchConfig, mesh,
     C = _capacity(n_loc, cfg, E_pad)
     cd = cfg.compute_dtype
 
-    def local(w_gate, w_up, w_down, router, x_loc):
+    def local(w_gate, w_up, w_down, router, bias, x_loc):
         # x_loc: (n_loc, d); w_*: (e_loc, d, ff)
         x_loc = x_loc.reshape(n_loc, d)
-        logits = x_loc.astype(jnp.float32) @ router          # (n_loc, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, expert_ids = jax.lax.top_k(probs, k)      # (n_loc, k)
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(-1, keepdims=True), 1e-9)
-
-        me = jax.lax.pmean(jnp.mean(probs, axis=0), axes)
-        ce = jax.lax.pmean(jnp.mean(jax.nn.one_hot(
-            expert_ids[:, 0], E, dtype=jnp.float32), axis=0), axes)
+        gate_vals, expert_ids, parts = route(router, bias, x_loc, cfg)
+        me, ce = jax.lax.pmean(parts, axes)
         aux = E * jnp.sum(me * ce)   # global-mean semantics == gspmd path
 
         # ---- build send buffers: slot = expert * C + rank ----
@@ -118,7 +112,7 @@ def moe_block_ep(p: MoEParams, x: jax.Array, cfg: ArchConfig, mesh,
     spec_w = P(axes, None, None)
     fn = jax.shard_map(
         local, mesh=mesh,
-        in_specs=(spec_w, spec_w, spec_w, P(None, None),
+        in_specs=(spec_w, spec_w, spec_w, P(None, None), P(None),
                   P(axes, None)),
         out_specs=(P(axes, None), P()),
         check_vma=False)
@@ -126,7 +120,8 @@ def moe_block_ep(p: MoEParams, x: jax.Array, cfg: ArchConfig, mesh,
     if n_pad != n:
         xt = jnp.concatenate(
             [xt, jnp.zeros((n_pad - n, d), xt.dtype)], axis=0)
-    out, aux = fn(p.w_gate, p.w_up, p.w_down, p.router, xt)
+    bias = p.bias if p.bias is not None else jnp.zeros((E,), jnp.float32)
+    out, aux = fn(p.w_gate, p.w_up, p.w_down, p.router, bias, xt)
     out = out[:n].reshape(B, T, d)
     if p.shared is not None:
         out = out + swiglu(p.shared, x.astype(cd), cd)

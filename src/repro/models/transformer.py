@@ -21,7 +21,8 @@ import jax.numpy as jnp
 
 from repro.distributed.logical import constrain
 
-from .attention import AttnParams, attention_block, init_attn, kv_zeros
+from .attention import (AttnParams, MLAParams, attention_block, init_attn,
+                        init_mla, kv_zeros, latent_zeros, mla_block)
 from .common import (ArchConfig, cross_entropy, dense_init, embed_init,
                      embed_lookup, rmsnorm, stacked)
 from .ffn import MLPParams, MoEParams, init_mlp, init_moe, moe_block, swiglu
@@ -165,7 +166,7 @@ class DenseLM:
 
 
 # ===========================================================================
-# MoE decoder LM (qwen2-moe / kimi-k2)
+# MoE decoder LM (qwen2-moe)
 # ===========================================================================
 class MoELayer(NamedTuple):
     attn: AttnParams
@@ -222,6 +223,135 @@ class MoELM(DenseLM):
 
     def _ffn(self, lp: MoELayer, x):
         return moe_block(lp.moe, x, self.cfg)[0]
+
+
+# ===========================================================================
+# Latent-attention MoE LM (Kimi-K2 / DeepSeek-V3 form)
+# ===========================================================================
+class LatentLayer(NamedTuple):
+    attn: MLAParams
+    ffn: MLPParams | MoEParams
+    norm1: jax.Array
+    norm2: jax.Array
+
+
+def _init_latent_layer(cfg: ArchConfig, dense: bool):
+    def init(key):
+        k1, k2 = jax.random.split(key)
+        ffn = init_mlp(k2, cfg.d_model, cfg.d_ff, cfg.param_dtype) \
+            if dense else init_moe(k2, cfg)
+        return LatentLayer(init_mla(k1, cfg), ffn,
+                           jnp.ones((cfg.d_model,), cfg.param_dtype),
+                           jnp.ones((cfg.d_model,), cfg.param_dtype))
+    return init
+
+
+class LatentMoELM:
+    """MLA + MoE decoder: ``n_dense_layers`` leading layers with a dense
+    SwiGLU of ``d_ff``, then a scan of MoE layers (routed experts of
+    ``expert_ff``, a shared expert, ``route``'s router). Every layer's
+    attention is MLA, and the serving cache is one stacked latent cache,
+    (L, slots, kv_lora_rank + qk_rope_dim, positions), that both scans
+    carry and write in place."""
+
+    AUX_WEIGHT = 0.01
+
+    def __init__(self, cfg: ArchConfig):
+        assert 0 <= cfg.n_dense_layers < cfg.n_layers
+        self.cfg = cfg
+        self.n_moe = cfg.n_layers - cfg.n_dense_layers
+
+    def init(self, key):
+        cfg = self.cfg
+        ke, kd, kl, ko = jax.random.split(key, 4)
+        return {
+            "embed": embed_init(ke, (cfg.vocab_size, cfg.d_model),
+                                cfg.param_dtype),
+            "dense_layers": stacked(_init_latent_layer(cfg, True),
+                                    cfg.n_dense_layers, kd),
+            "layers": stacked(_init_latent_layer(cfg, False), self.n_moe,
+                              kl),
+            "final_norm": jnp.ones((cfg.d_model,), cfg.param_dtype),
+            "lm_head": dense_init(ko, (cfg.d_model, cfg.vocab_size),
+                                  dtype=cfg.param_dtype),
+        }
+
+    def _layer(self, lp: LatentLayer, x, *, dense: bool, cache=None,
+               layer=None, index=None):
+        cfg = self.cfg
+        with jax.named_scope("mla"):
+            a, cache = mla_block(lp.attn, rmsnorm(x, lp.norm1, cfg.norm_eps),
+                                 cfg, cache=cache, layer=layer,
+                                 cache_index=index)
+        x = constrain(x + a, "batch", "seq", "embed")
+        n = rmsnorm(x, lp.norm2, cfg.norm_eps)
+        if dense:
+            f, aux = swiglu(lp.ffn, n, cfg.compute_dtype), jnp.zeros(())
+        else:
+            f, aux = moe_block(lp.ffn, n, cfg)
+        return x + f, cache, aux
+
+    def _stack(self, params, h, cache=None, index=None):
+        """Both scans, dense then MoE: (h, new latent cache, the MoE
+        layers' mean aux)."""
+        cfg = self.cfg
+        auxes = []
+        for name, dense, first in (("dense_layers", True, 0),
+                                   ("layers", False, cfg.n_dense_layers)):
+            n_layers = cfg.n_dense_layers if dense else self.n_moe
+
+            def body(carry, inp, dense=dense):
+                x, c = carry
+                lp, layer = inp
+                x, c, aux = self._layer(lp, x, dense=dense, cache=c,
+                                        layer=layer, index=index)
+                return (constrain(x, "batch", "seq_res", "embed"), c), aux
+
+            if cache is None:
+                body = _maybe_remat(body, cfg)
+            (h, cache), aux = jax.lax.scan(
+                body, (h, cache),
+                (params[name], first + jnp.arange(n_layers)))
+            auxes.append(aux)
+        h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
+        return h, cache, jnp.mean(auxes[-1])
+
+    def _embed(self, params, tokens):
+        cfg = self.cfg
+        return constrain(embed_lookup(params["embed"], tokens,
+                                      cfg.compute_dtype),
+                         "batch", "seq", "embed")
+
+    def loss_fn(self, params, batch):
+        cfg = self.cfg
+        h, _, aux = self._stack(params, self._embed(params, batch["tokens"]))
+        logits = constrain(
+            h @ params["lm_head"].astype(cfg.compute_dtype),
+            "batch", "seq", "vocab")
+        return cross_entropy(logits, batch["labels"],
+                             batch.get("loss_mask")) + self.AUX_WEIGHT * aux
+
+    # -- serving ------------------------------------------------------------
+    def init_cache(self, batch: int, max_len: int):
+        cfg = self.cfg
+        return {"latent": latent_zeros(cfg, cfg.n_layers, batch, max_len),
+                "index": jnp.zeros((batch,), jnp.int32)}
+
+    def _cached(self, params, tokens, cache):
+        idx = cache["index"]
+        h, latent, _ = self._stack(params, self._embed(params, tokens),
+                                   cache["latent"], idx)
+        return h, {"latent": latent, "index": idx + h.shape[1]}
+
+    def prefill(self, params, batch, cache):
+        h, cache = self._cached(params, batch["tokens"], cache)
+        logits = h[:, -1:] @ params["lm_head"].astype(self.cfg.compute_dtype)
+        return logits, cache
+
+    def decode(self, params, tokens, cache):
+        h, cache = self._cached(params, tokens, cache)
+        logits = h @ params["lm_head"].astype(self.cfg.compute_dtype)
+        return logits, cache
 
 
 # ===========================================================================
@@ -616,6 +746,7 @@ FAMILIES = {
     "dense": DenseLM,
     "vlm": DenseLM,
     "moe": MoELM,
+    "mla_moe": LatentMoELM,
     "ssm": MambaLM,
     "hybrid": HybridLM,
     "encdec": EncDecLM,

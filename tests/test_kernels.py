@@ -10,7 +10,8 @@ hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import (decode_attention,
+                                            mla_decode_attention)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 
@@ -133,6 +134,59 @@ def test_decode_attention_ragged_property(lens, layer):
                                      jnp.minimum(lengths, S - 1))
     np.testing.assert_allclose(np.asarray(masked_same), np.asarray(want2),
                                rtol=3e-5, atol=3e-5)
+
+
+# ---------------------------------------------------------------------------
+# MLA decode — absorbed queries against the stacked latent cache
+# (L, B, rank + rope, S), the first ``rank`` rows doubling as V
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("L,layer,B,S,H,rank,rope,bk", [
+    (1, 0, 1, 64, 4, 32, 8, 32),
+    (3, 2, 4, 256, 8, 64, 16, 64),
+    (2, 1, 2, 128, 16, 32, 8, 128),     # one kv block
+])
+def test_mla_decode_attention_sweep(L, layer, B, S, H, rank, rope, bk,
+                                    dtype):
+    key = jax.random.key(hash((L, B, S, H, rank)) % 2**31)
+    q = rand(key, (B, H, rank + rope), dtype)
+    cache = rand(jax.random.fold_in(key, 1), (L, B, rank + rope, S), dtype)
+    lengths = jax.random.randint(jax.random.fold_in(key, 2), (B,), 1, S + 1)
+    scale = 0.13
+    out = mla_decode_attention(q, cache, jnp.int32(layer), lengths,
+                               scale=scale, rank=rank, blk_k=bk,
+                               interpret=True)
+    want = ref.mla_decode_attention_ref(q, cache, layer, lengths,
+                                        scale=scale, rank=rank)
+    assert out.shape == (B, H, rank)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), **TOL[dtype])
+    # positions past each length, and the other layers, do not count
+    junk = cache.at[:, :, :, -1].set(99.0)
+    if L > 1:
+        junk = junk.at[(layer + 1) % L].set(-99.0)
+    short = jnp.minimum(lengths, S - 1)
+    np.testing.assert_allclose(
+        np.asarray(mla_decode_attention(q, junk, jnp.int32(layer), short,
+                                        scale=scale, rank=rank, blk_k=bk,
+                                        interpret=True), np.float32),
+        np.asarray(ref.mla_decode_attention_ref(q, cache, layer, short,
+                                                scale=scale, rank=rank),
+                   np.float32), **TOL[dtype])
+
+
+def test_flash_attention_narrow_v_and_given_scale():
+    """MLA's prefill form: v narrower than q/k, a softmax scale given."""
+    key = jax.random.key(5)
+    q = rand(key, (1, 64, 4, 24), jnp.float32)
+    k = rand(jax.random.fold_in(key, 1), (1, 64, 4, 24), jnp.float32)
+    v = rand(jax.random.fold_in(key, 2), (1, 64, 4, 16), jnp.float32)
+    out = flash_attention(q, k, v, causal=True, scale=0.3, blk_q=16,
+                          blk_k=32, interpret=True)
+    want = ref.flash_attention_ref(q, k, v, causal=True, scale=0.3)
+    assert out.shape == (1, 64, 4, 16)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import (decode_attention,
+                                            mla_decode_attention)
 from repro.kernels.flash_attention import flash_attention
 from repro.models.transformer import build_model
 
@@ -105,6 +106,23 @@ def test_decode_attention_compiles(one_chip, L, B, S, Hq, Hkv, hd):
     layer = _spec((), jnp.int32, one_chip)
     lengths = _spec((B,), jnp.int32, one_chip)
     hlo, nbytes = _compile(decode_attention, q, kv, kv, layer, lengths)
+    assert "tpu_custom_call" in hlo
+    assert nbytes < V5E_HBM_BYTES
+
+
+@pytest.mark.usefixtures("no_persistent_cache")
+def test_mla_decode_attention_compiles_kimi_widths(one_chip):
+    """The MLA decode kernel at kimi-k2-chip's widths and the benchmark
+    cell's cache: 9 layers x 4 slots x 16640 positions of 512 + 64
+    latent values, 64 heads."""
+    q = _spec((4, 64, 576), jnp.bfloat16, one_chip)
+    cache = _spec((9, 4, 576, 16640), jnp.bfloat16, one_chip)
+    layer = _spec((), jnp.int32, one_chip)
+    lengths = _spec((4,), jnp.int32, one_chip)
+    hlo, nbytes = _compile(
+        lambda q, c, l, n: mla_decode_attention(q, c, l, n, scale=0.13,
+                                                rank=512),
+        q, cache, layer, lengths)
     assert "tpu_custom_call" in hlo
     assert nbytes < V5E_HBM_BYTES
 
@@ -212,3 +230,35 @@ def test_phi3_mini_decode_step_keeps_the_cache_in_place(one_chip):
     kernels = [i for i in comps[bodies[0]] if i["op"] == "custom-call"
                and "decode_attention" in i["name"]]
     assert len(kernels) == 1                    # one call per layer
+
+
+@pytest.mark.usefixtures("no_persistent_cache", "pallas_backend")
+def test_kimi_chip_decode_step_keeps_the_latent_cache_in_place(one_chip):
+    """The ``kimi-k2.engine-longprompt`` decode step (kimi-k2-chip at
+    published widths, 4 slots x 16640 positions) carries the stacked latent
+    cache through the dense layer and the MoE layers' scan: no op
+    materialises one layer's latent cache, the only whole-cache buffer is
+    the undonated call's copy, each layer calls the MLA kernel once, and
+    the held experts run XLA's ragged dot."""
+    cfg = get_config("kimi-k2-chip")
+    B, S = 4, 16640
+    compiled = _decode_step(one_chip, "kimi-k2-chip", B, S)
+    comps, entry = _instructions(compiled.as_text())
+    bodies = [re.search(r"body=%([\w.\-]+)", i["text"]).group(1)
+              for i in comps[entry] if i["op"] == "while"]
+    # the MoE layers' scan; XLA unrolls the dense layer's scan of one
+    assert len(bodies) == 1
+    layer = B * (cfg.kv_lora_rank + cfg.qk_rope_dim) * S
+    whole = []
+    for comp, layers in ((entry, cfg.n_dense_layers),
+                         (bodies[0], 1)):
+        assert _buffers(comps, comp, layer) == []
+        whole += _buffers(comps, comp, cfg.n_layers * layer)
+        kernels = [i for i in comps[comp] if i["op"] == "custom-call"
+                   and "mla_decode_attention" in i["name"]]
+        assert len(kernels) == layers
+    assert len(whole) <= 1, whole
+    assert "ragged-dot" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes < V5E_HBM_BYTES
